@@ -129,6 +129,8 @@ def main(full: bool = False, json_path=None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--json", action="store_true")

@@ -173,22 +173,21 @@ def _seed_sequential_saturation(tab, step, max_rate, cycles, warmup,
     sim = _seed_simulate_factory()
     meas = cycles - warmup
     sat, trace, rate = 0.0, [], step
-    with jax.experimental.disable_x64():
-        while rate <= max_rate + 1e-9:
-            off, acc, dlv = sim(
-                jnp.asarray(tab.ch_dst), jnp.asarray(tab.path),
-                jnp.asarray(tab.vcs), jnp.float32(rate),
-                jax.random.PRNGKey(0), n=tab.n, n_ch=tab.n_ch,
-                n_vc=tab.n_vc, slots=slots, cycles=cycles, warmup=warmup,
-                flits=flits)
-            r = {"offered": float(off) / meas / tab.n,
-                 "delivered": float(dlv) / meas / tab.n, "rate": rate}
-            trace.append(r)
-            if r["delivered"] >= (1 - deficit) * r["offered"]:
-                sat = r["delivered"]
-            else:
-                break
-            rate += step
+    while rate <= max_rate + 1e-9:
+        off, acc, dlv = sim(
+            jnp.asarray(tab.ch_dst), jnp.asarray(tab.path),
+            jnp.asarray(tab.vcs), jnp.float32(rate),
+            jax.random.PRNGKey(0), n=tab.n, n_ch=tab.n_ch,
+            n_vc=tab.n_vc, slots=slots, cycles=cycles, warmup=warmup,
+            flits=flits)
+        r = {"offered": float(off) / meas / tab.n,
+             "delivered": float(dlv) / meas / tab.n, "rate": rate}
+        trace.append(r)
+        if r["delivered"] >= (1 - deficit) * r["offered"]:
+            sat = r["delivered"]
+        else:
+            break
+        rate += step
     return sat, trace
 
 
@@ -416,6 +415,8 @@ def main(full: bool = False, json_path=None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--json", action="store_true")

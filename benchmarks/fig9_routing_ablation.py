@@ -77,6 +77,8 @@ def main(full: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     main(ap.parse_args().full)

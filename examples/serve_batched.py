@@ -11,4 +11,6 @@ sys.path.insert(0, str(Path(__file__).parent.parent / "src"))
 from repro.launch.serve import main
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     main()

@@ -21,8 +21,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_enable_x64", True)  # LP numerics need f64
-
 
 @dataclasses.dataclass
 class COOMatrix:
@@ -128,80 +126,86 @@ def solve_pdhg(c, A: COOMatrix, b, lo, hi, max_iters: int = 40000,
                x0: Optional[np.ndarray] = None,
                y0: Optional[np.ndarray] = None,
                verbose: bool = False) -> LPResult:
-    m, n = A.shape
-    c = np.asarray(c, np.float64)
-    b = np.asarray(b, np.float64)
-    lo = np.asarray(lo, np.float64)
-    hi = np.asarray(hi, np.float64)
+    """Matrix-free PDHG with restarts; the iterates stay in f64.
 
-    vals_s, dr, dc = _ruiz_scale(A)
-    # scaled problem: x = Dc xs, rows scaled by Dr:
-    cs = c * dc
-    bs = b * dr
-    los = lo / dc
-    his = hi / dc
+    64-bit mode is switched on for this call only, so the f64 arrays and
+    the ``_pdhg_chunk`` executions live inside it and nothing else in the
+    process changes dtype."""
+    with jax.enable_x64(True):
+        m, n = A.shape
+        c = np.asarray(c, np.float64)
+        b = np.asarray(b, np.float64)
+        lo = np.asarray(lo, np.float64)
+        hi = np.asarray(hi, np.float64)
 
-    A_sp = A.to_scipy()
+        vals_s, dr, dc = _ruiz_scale(A)
+        # scaled problem: x = Dc xs, rows scaled by Dr:
+        cs = c * dc
+        bs = b * dr
+        los = lo / dc
+        his = hi / dc
 
-    # spectral norm of the scaled operator (power iteration)
-    import scipy.sparse as sp
-    As = sp.coo_matrix((vals_s, (A.rows, A.cols)), shape=A.shape).tocsr()
-    v = np.random.default_rng(0).normal(size=n)
-    v /= np.linalg.norm(v)
-    for _ in range(60):
-        w = As.T @ (As @ v)
-        nw = np.linalg.norm(w)
-        if nw == 0:
-            break
-        v = w / nw
-    norm = float(np.sqrt(max(v @ (As.T @ (As @ v)), 1e-12)))
-    step = 0.9 / max(norm, 1e-9)
-    tau = sigma = step
+        A_sp = A.to_scipy()
 
-    rows_j = jnp.asarray(A.rows)
-    cols_j = jnp.asarray(A.cols)
-    vals_j = jnp.asarray(vals_s, jnp.float64)
-    cj = jnp.asarray(cs)
-    bj = jnp.asarray(bs)
-    loj = jnp.asarray(los)
-    hij = jnp.asarray(his)
+        # spectral norm of the scaled operator (power iteration)
+        import scipy.sparse as sp
+        As = sp.coo_matrix((vals_s, (A.rows, A.cols)), shape=A.shape).tocsr()
+        v = np.random.default_rng(0).normal(size=n)
+        v /= np.linalg.norm(v)
+        for _ in range(60):
+            w = As.T @ (As @ v)
+            nw = np.linalg.norm(w)
+            if nw == 0:
+                break
+            v = w / nw
+        norm = float(np.sqrt(max(v @ (As.T @ (As @ v)), 1e-12)))
+        step = 0.9 / max(norm, 1e-9)
+        tau = sigma = step
 
-    x = np.clip(x0 / dc, los, his) if x0 is not None \
-        else np.clip(np.zeros(n), los, his)
-    y = (y0 / dr) if y0 is not None else np.zeros(m)
-    xj = jnp.asarray(x)
-    yj = jnp.asarray(np.maximum(y, 0.0))
+        rows_j = jnp.asarray(A.rows)
+        cols_j = jnp.asarray(A.cols)
+        vals_j = jnp.asarray(vals_s, jnp.float64)
+        cj = jnp.asarray(cs)
+        bj = jnp.asarray(bs)
+        loj = jnp.asarray(los)
+        hij = jnp.asarray(his)
 
-    best = None
-    it = 0
-    while it < max_iters:
-        xj, yj, xavg, yavg = _pdhg_chunk(rows_j, cols_j, vals_j, cj, bj,
-                                         loj, hij, xj, yj, tau, sigma,
-                                         m, n, inner)
-        it += inner
-        # evaluate averaged and current iterates in the original space
-        x_avg_u = np.asarray(xavg) * dc
-        y_avg_u = np.asarray(yavg) * dr
-        x_cur_u = np.asarray(xj) * dc
-        y_cur_u = np.asarray(yj) * dr
-        for xu, yu, tag in ((x_avg_u, y_avg_u, "avg"),
-                            (x_cur_u, y_cur_u, "cur")):
-            pobj, dobj, gap, pinf = _residuals(A_sp, c, b, lo, hi, xu, yu)
-            if best is None or (gap + pinf) < (best[2] + best[3]):
-                best = (xu, yu, gap, pinf, pobj, tag)
-        if verbose:
-            print(f"  pdhg it={it} gap={best[2]:.2e} pinf={best[3]:.2e} "
-                  f"obj={best[4]:.6g} ({best[5]})")
-        if best[2] < tol and best[3] < tol:
-            break
-        # restart from the best candidate (rescaled)
-        xj = jnp.asarray(best[0] / dc)
-        yj = jnp.asarray(best[1] / dr)
+        x = np.clip(x0 / dc, los, his) if x0 is not None \
+            else np.clip(np.zeros(n), los, his)
+        y = (y0 / dr) if y0 is not None else np.zeros(m)
+        xj = jnp.asarray(x)
+        yj = jnp.asarray(np.maximum(y, 0.0))
 
-    xu, yu, gap, pinf, pobj, _ = best
-    status = "optimal" if (gap < tol and pinf < tol) else "max_iters"
-    return LPResult(xu, yu, pobj, status, iters=it, rel_gap=gap,
-                    primal_infeas=pinf)
+        best = None
+        it = 0
+        while it < max_iters:
+            xj, yj, xavg, yavg = _pdhg_chunk(rows_j, cols_j, vals_j, cj, bj,
+                                             loj, hij, xj, yj, tau, sigma,
+                                             m, n, inner)
+            it += inner
+            # evaluate averaged and current iterates in the original space
+            x_avg_u = np.asarray(xavg) * dc
+            y_avg_u = np.asarray(yavg) * dr
+            x_cur_u = np.asarray(xj) * dc
+            y_cur_u = np.asarray(yj) * dr
+            for xu, yu, tag in ((x_avg_u, y_avg_u, "avg"),
+                                (x_cur_u, y_cur_u, "cur")):
+                pobj, dobj, gap, pinf = _residuals(A_sp, c, b, lo, hi, xu, yu)
+                if best is None or (gap + pinf) < (best[2] + best[3]):
+                    best = (xu, yu, gap, pinf, pobj, tag)
+            if verbose:
+                print(f"  pdhg it={it} gap={best[2]:.2e} pinf={best[3]:.2e} "
+                      f"obj={best[4]:.6g} ({best[5]})")
+            if best[2] < tol and best[3] < tol:
+                break
+            # restart from the best candidate (rescaled)
+            xj = jnp.asarray(best[0] / dc)
+            yj = jnp.asarray(best[1] / dr)
+
+        xu, yu, gap, pinf, pobj, _ = best
+        status = "optimal" if (gap < tol and pinf < tol) else "max_iters"
+        return LPResult(xu, yu, pobj, status, iters=it, rel_gap=gap,
+                        primal_infeas=pinf)
 
 
 def solve(c, A: COOMatrix, b, lo, hi, prefer: str = "auto",

@@ -46,7 +46,8 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Dict, List, Optional, Sequence, Tuple, Union
+from typing import (Callable, Dict, List, Optional, Sequence, Tuple,
+                    Union)
 
 import numpy as np
 
@@ -58,7 +59,7 @@ from repro.core.routing import (ATResult, Channels, RoutingResult,
                                 _dead_channel_array)
 from repro.core.topology import Topology
 from repro.core.traffic import (CompiledFlowTraffic, CompiledTraffic,
-                                PhasedTraffic, TrafficPattern,
+                                PhasedTraffic, TenantMap, TrafficPattern,
                                 compile_flow_traffic)
 
 
@@ -880,50 +881,28 @@ def adaptive_spec(topo: Topology,
         np.stack([a0.minmask, a1.minmask]).astype(np.uint8))
 
 
-def sweep(tables: SimTables, rates: Sequence[float],
-          traffic: Optional[Union[TrafficPattern, CompiledTraffic,
-                                  CompiledFlowTraffic,
-                                  PhasedTraffic]] = None,
-          cycles: int = 6000, warmup: int = 2000, slots: int = 128,
-          seed: int = 0, flits: int = 4, kernel: str = "csr",
-          stats: Optional[dict] = None,
-          adaptive: Optional[AdaptiveSpec] = None,
-          fault: Optional[Tuple[int, Sequence[int]]] = None,
-          patience: int = 64, watchdog: int = 512) -> List[Dict]:
-    """Simulate every rate in one batched (lane-flattened) kernel
-    execution; one dict per rate.
+@dataclasses.dataclass
+class _SweepCall:
+    """One sweep's kernel execution: ``fn(*args, **static)`` is exactly
+    what :func:`sweep` runs (``fn`` is None when the traffic routes no
+    flow), plus what it needs to decode the result."""
+    fn: Optional[Callable]
+    args: tuple
+    static: dict
+    rates: np.ndarray
+    tenants: Optional[TenantMap]
+    array_bytes: int
 
-    ``kernel="csr"`` (default) gathers routes from the CSR hop arrays
-    and never touches the dense ``(n, n, MAXHOP)`` tables;
-    ``kernel="dense"`` runs the legacy dense-gather kernel on the same
-    flow-slot traffic tables and RNG stream -- the counters of the two
-    kernels are bit-identical (the CSR parity tests rely on it). A
-    ``stats`` dict, when given, records the kernel used and the peak
-    device-array bytes staged per call under ``"array_bytes"``.
 
-    ``adaptive`` (an :func:`adaptive_spec` result) switches both kernels
-    to occupancy-driven minimal adaptive routing with the VC0 escape
-    lane; requires ``n_vc >= 2`` tables (VC0 reserved -- allocate with
-    ``reserve_escape=True``). ``fault=(t, dead_channels)`` kills the
-    given channels at cycle ``t`` mid-sweep: dead channels stop
-    accepting forwards/injections (their receive queues still drain),
-    and with ``adaptive`` set, in-flight packets re-resolve onto
-    surviving alternates or the re-rooted escape tree. ``patience`` is
-    the per-queue stalled-cycles threshold before an adaptive head
-    diverts to the escape VC; ``watchdog`` is the zero-progress window
-    after which a lane is declared stalled (``stalled_at`` per rate,
-    ``stats["cycles_run"]`` < ``cycles`` when every lane wedged and the
-    sweep aborted early).
+def _sweep_call(tables: SimTables, rates: Sequence[float], traffic, *,
+                cycles: int, warmup: int, slots: int, seed: int,
+                flits: int, kernel: str, adaptive: Optional[AdaptiveSpec],
+                fault: Optional[Tuple[int, Sequence[int]]], patience: int,
+                watchdog: int) -> _SweepCall:
+    """Validate a :func:`sweep` request and assemble its kernel call.
 
-    A :class:`PhasedTraffic` input switches both kernels to trace
-    replay: the spatial demand phase follows the compiled schedule
-    cycle by cycle. A pattern carrying a
-    :class:`~repro.core.traffic.TenantMap` (from
-    :func:`~repro.core.traffic.compose_tenants`) adds a ``"tenants"``
-    entry to every rate dict -- per-tenant injected / consumed /
-    in-flight packet counts (exact conservation: injected == consumed +
-    in-flight) and delivered throughput per tenant node.
-    """
+    Both :func:`sweep` and the ahead-of-time compile tests go through
+    here, so a test lowers exactly the program a sweep would run."""
     if MAXHOP > _HOP_MASK:
         raise ValueError(f"packed packet words support MAXHOP <= "
                          f"{_HOP_MASK}")
@@ -1006,16 +985,8 @@ def sweep(tables: SimTables, rates: Sequence[float],
                  + alive_np.nbytes + phase_np.nbytes + tof_np.nbytes
                  + tmap_np.nbytes + phase_of_np.nbytes)
     if F == 0:
-        if stats is not None:
-            stats["kernel"] = kernel
-            stats["cycles_run"] = cycles
-            stats["array_bytes"] = max(stats.get("array_bytes", 0),
-                                       state_bytes + traffic_bytes)
-        return [{"rate": float(r), "offered": 0.0, "accepted": 0.0,
-                 "delivered": 0.0, "delivered_tagged": 0.0,
-                 "consumed_total": 0, "injected_total": 0, "in_flight": 0,
-                 "escaped": 0, "stalled_at": -1}
-                for r in rates]
+        return _SweepCall(None, (), {}, rates, tenants,
+                          state_bytes + traffic_bytes)
     if kernel == "csr":
         t = tables.csr()
         if t.n_flows > _FLOW_MASK:
@@ -1052,30 +1023,89 @@ def sweep(tables: SimTables, rates: Sequence[float],
         fn = _sweep_dense
     else:
         raise ValueError(f"unknown kernel {kernel!r}")
+    args = args + (
+        jnp.asarray(ct.src_indptr[:-1]), jnp.asarray(ct.deg),
+        jnp.asarray(ct.prob), jnp.asarray(ct.alias),
+        jnp.asarray(ct.src_rate), jnp.asarray(rates),
+        jax.random.PRNGKey(seed), jnp.asarray(outch_np),
+        jnp.asarray(minmask_np), jnp.asarray(esc_np), jnp.asarray(alive_np),
+        jnp.int32(t_fault), jnp.float32(g_on), jnp.float32(g_off),
+        jnp.asarray(np.asarray(phase_np, np.int32)), jnp.asarray(tof_np),
+        jnp.asarray(tmap_np), jnp.asarray(phase_of_np))
+    static = dict(R=R, n=tables.n, n_ch=tables.n_ch, n_vc=tables.n_vc,
+                  slots=slots, cycles=cycles, warmup=warmup, flits=flits,
+                  adaptive=adaptive_on, faulted=faulted, bursty=bursty,
+                  patience=patience, watchdog=watchdog, D=D, period=period,
+                  on_cycles=on_cycles, T=T, phased=phased,
+                  p_period=p_period)
+    return _SweepCall(fn, args, static, rates, tenants,
+                      state_bytes + traffic_bytes + route_bytes
+                      + aux_bytes)
+
+
+def sweep(tables: SimTables, rates: Sequence[float],
+          traffic: Optional[Union[TrafficPattern, CompiledTraffic,
+                                  CompiledFlowTraffic,
+                                  PhasedTraffic]] = None,
+          cycles: int = 6000, warmup: int = 2000, slots: int = 128,
+          seed: int = 0, flits: int = 4, kernel: str = "csr",
+          stats: Optional[dict] = None,
+          adaptive: Optional[AdaptiveSpec] = None,
+          fault: Optional[Tuple[int, Sequence[int]]] = None,
+          patience: int = 64, watchdog: int = 512) -> List[Dict]:
+    """Simulate every rate in one batched (lane-flattened) kernel
+    execution; one dict per rate.
+
+    ``kernel="csr"`` (default) gathers routes from the CSR hop arrays
+    and never touches the dense ``(n, n, MAXHOP)`` tables;
+    ``kernel="dense"`` runs the legacy dense-gather kernel on the same
+    flow-slot traffic tables and RNG stream -- the counters of the two
+    kernels are bit-identical (the CSR parity tests rely on it). A
+    ``stats`` dict, when given, records the kernel used and the peak
+    device-array bytes staged per call under ``"array_bytes"``.
+
+    ``adaptive`` (an :func:`adaptive_spec` result) switches both kernels
+    to occupancy-driven minimal adaptive routing with the VC0 escape
+    lane; requires ``n_vc >= 2`` tables (VC0 reserved -- allocate with
+    ``reserve_escape=True``). ``fault=(t, dead_channels)`` kills the
+    given channels at cycle ``t`` mid-sweep: dead channels stop
+    accepting forwards/injections (their receive queues still drain),
+    and with ``adaptive`` set, in-flight packets re-resolve onto
+    surviving alternates or the re-rooted escape tree. ``patience`` is
+    the per-queue stalled-cycles threshold before an adaptive head
+    diverts to the escape VC; ``watchdog`` is the zero-progress window
+    after which a lane is declared stalled (``stalled_at`` per rate,
+    ``stats["cycles_run"]`` < ``cycles`` when every lane wedged and the
+    sweep aborted early).
+
+    A :class:`PhasedTraffic` input switches both kernels to trace
+    replay: the spatial demand phase follows the compiled schedule
+    cycle by cycle. A pattern carrying a
+    :class:`~repro.core.traffic.TenantMap` (from
+    :func:`~repro.core.traffic.compose_tenants`) adds a ``"tenants"``
+    entry to every rate dict -- per-tenant injected / consumed /
+    in-flight packet counts (exact conservation: injected == consumed +
+    in-flight) and delivered throughput per tenant node.
+    """
+    call = _sweep_call(tables, rates, traffic, cycles=cycles,
+                       warmup=warmup, slots=slots, seed=seed, flits=flits,
+                       kernel=kernel, adaptive=adaptive, fault=fault,
+                       patience=patience, watchdog=watchdog)
     if stats is not None:
         stats["kernel"] = kernel
         stats["array_bytes"] = max(stats.get("array_bytes", 0),
-                                   state_bytes + traffic_bytes
-                                   + route_bytes + aux_bytes)
-    # the simulator's integer carries are written for 32-bit mode; shield
-    # it from processes that enabled x64 (e.g. the LP solver)
-    with jax.experimental.disable_x64():
-        out = fn(*args, jnp.asarray(ct.src_indptr[:-1]),
-                 jnp.asarray(ct.deg), jnp.asarray(ct.prob),
-                 jnp.asarray(ct.alias), jnp.asarray(ct.src_rate),
-                 jnp.asarray(rates), jax.random.PRNGKey(seed),
-                 jnp.asarray(outch_np), jnp.asarray(minmask_np),
-                 jnp.asarray(esc_np), jnp.asarray(alive_np),
-                 jnp.int32(t_fault), jnp.float32(g_on), jnp.float32(g_off),
-                 jnp.asarray(np.asarray(phase_np, np.int32)),
-                 jnp.asarray(tof_np), jnp.asarray(tmap_np),
-                 jnp.asarray(phase_of_np), R=R,
-                 n=tables.n, n_ch=tables.n_ch, n_vc=tables.n_vc,
-                 slots=slots, cycles=cycles, warmup=warmup, flits=flits,
-                 adaptive=adaptive_on, faulted=faulted, bursty=bursty,
-                 patience=patience, watchdog=watchdog, D=D, period=period,
-                 on_cycles=on_cycles, T=T, phased=phased,
-                 p_period=p_period)
+                                   call.array_bytes)
+    if call.fn is None:
+        if stats is not None:
+            stats["cycles_run"] = cycles
+        return [{"rate": float(r), "offered": 0.0, "accepted": 0.0,
+                 "delivered": 0.0, "delivered_tagged": 0.0,
+                 "consumed_total": 0, "injected_total": 0, "in_flight": 0,
+                 "escaped": 0, "stalled_at": -1}
+                for r in call.rates]
+    out = call.fn(*call.args, **call.static)
+    rates, tenants = call.rates, call.tenants
+    T = call.static["T"]
     (off, acc, tagd, consm, cons, injd, escd, infl, stalled,
      inj_t, cons_t, consm_t, infl_t) = (np.asarray(a) for a in out[:-1])
     cycles_run = int(out[-1])

@@ -65,7 +65,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 
 def flash_attention(q, k, v, *, causal: bool = True, bq: int = 128,
-                    bk: int = 128, interpret: bool = True):
+                    bk: int = 128, interpret: bool = False):
     """q: (B, Hq, Sq, hd); k, v: (B, Hkv, Skv, hd)."""
     B, Hq, Sq, hd = q.shape
     Hkv, Skv = k.shape[1], k.shape[2]

@@ -41,7 +41,7 @@ def _kernel(a_ref, b_ref, o_ref, acc_ref, *, bk: int):
 
 
 def minplus(a, b, *, bm: int = 128, bn: int = 128, bk: int = 128,
-            interpret: bool = True):
+            interpret: bool = False):
     """out[i, j] = min_k a[i, k] + b[k, j]; a: (M, K), b: (K, N) f32."""
     M, K = a.shape
     K2, N = b.shape
@@ -64,7 +64,7 @@ def minplus(a, b, *, bm: int = 128, bn: int = 128, bk: int = 128,
     )(a, b)
 
 
-def apsp(adj, *, interpret: bool = True, block: int = 128):
+def apsp(adj, *, interpret: bool = False, block: int = 128):
     """All-pairs hop distances by log-depth (min,+) squaring."""
     import math
     n = adj.shape[0]

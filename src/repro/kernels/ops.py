@@ -1,13 +1,12 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels execute under interpret=True; on real
-TPU hardware set REPRO_PALLAS_COMPILE=1 (or pass interpret=False) to lower
-them natively. The jnp reference implementations remain available as
-oracles and as the XLA fallback the models use for the dry-run.
+The wrappers compile the kernels for the TPU. ``interpret=True`` runs
+them in the Pallas interpreter instead, which is how the CPU tests call
+them. The jnp reference implementations remain available as oracles and
+as the XLA fallback the models use for the dry-run.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -18,19 +17,17 @@ from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.minplus import apsp as _apsp, minplus as _minplus
 
-_INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
-
-
-@partial(jax.jit, static_argnames=("causal", "bq", "bk"))
+@partial(jax.jit, static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention(q, k, v, causal: bool = True, bq: int = 128,
-                    bk: int = 128):
+                    bk: int = 128, interpret: bool = False):
     return _flash(q, k, v, causal=causal, bq=bq, bk=bk,
-                  interpret=_INTERPRET)
+                  interpret=interpret)
 
 
-@partial(jax.jit, static_argnames=("bm", "bn", "bk"))
-def minplus(a, b, bm: int = 128, bn: int = 128, bk: int = 128):
-    return _minplus(a, b, bm=bm, bn=bn, bk=bk, interpret=_INTERPRET)
+@partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+def minplus(a, b, bm: int = 128, bn: int = 128, bk: int = 128,
+            interpret: bool = False):
+    return _minplus(a, b, bm=bm, bn=bn, bk=bk, interpret=interpret)
 
 
 def hop_matrix(edges: np.ndarray, n: int) -> jnp.ndarray:
@@ -42,7 +39,8 @@ def hop_matrix(edges: np.ndarray, n: int) -> jnp.ndarray:
     return jnp.asarray(d)
 
 
-def topology_metrics(edges: np.ndarray, n: int, block: int = 128):
+def topology_metrics(edges: np.ndarray, n: int, block: int = 128,
+                     interpret: bool = False):
     """Diameter + average hops via the Pallas APSP path (padded to the
     block size)."""
     pad = (-n) % block
@@ -50,7 +48,7 @@ def topology_metrics(edges: np.ndarray, n: int, block: int = 128):
     if pad:
         d0 = jnp.pad(d0, ((0, pad), (0, pad)), constant_values=1e9)
         d0 = d0.at[jnp.arange(n, n + pad), jnp.arange(n, n + pad)].set(0.0)
-    d = _apsp(d0, interpret=_INTERPRET, block=block)
+    d = _apsp(d0, interpret=interpret, block=block)
     d = d[:n, :n]
     diam = int(jnp.max(jnp.where(d >= 1e8, -1, d)))
     avg = float(jnp.sum(jnp.where(d >= 1e8, 0, d)) / (n * (n - 1)))

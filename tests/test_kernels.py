@@ -55,7 +55,8 @@ def test_minplus_sweep(shape):
 def test_apsp_matches_scipy():
     from repro.core import topology as T
     topo = T.pt((4, 4, 8))
-    d_kernel, h_kernel = ops.topology_metrics(topo.edges(), topo.n)
+    d_kernel, h_kernel = ops.topology_metrics(topo.edges(), topo.n,
+                                              interpret=True)
     d_ref, h_ref = T.diameter_avg_hops(topo)
     assert d_kernel == d_ref
     assert abs(h_kernel - h_ref) < 1e-3
