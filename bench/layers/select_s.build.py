@@ -1,0 +1,7 @@
+"""select_s.build: mean seconds of RoutedPod.timings["select_s"] over the
+window's builds."""
+
+
+def read(run):
+    t = [o["timings"]["select_s"] for o in run.outputs if o is not None]
+    return sum(t) / len(t) if t else None
