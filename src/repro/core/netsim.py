@@ -54,6 +54,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.core import obs
 from repro.core.pathtable import MAXHOP, CSRPathTable, PathTable
 from repro.core.routing import (ATResult, Channels, RoutingResult,
                                 _dead_channel_array)
@@ -254,253 +255,263 @@ def _sweep_csr(ch_dst, pvf, hptr, lenm1, dstN, src_ptr, deg, fprob, falias,
         i, q, head, size, rr, busy, key, stall, wstall, stalled_at, \
             stats = carry
         (offered, accepted, tagged, consumed_meas, consumed, injected,
-         escaped, inj_t, cons_t, consm_t) = stats
+         escaped, hops, inj_t, cons_t, consm_t) = stats
         ph = (i >= t_fault).astype(jnp.int32) if faulted else 0
         phz = phase_of[i % p_period] if phased else 0
 
-        # ---- head packet per (lane, channel, vc) --------------------------
-        hw = q[jnp.arange(NQ), head]
-        hf = hw & _FLOW_MASK
-        hh = (hw >> _HOP_SHIFT) & _HOP_MASK
-        nonempty = size > 0
+        with jax.named_scope("route"):
+            # ---- head packet per (lane, channel, vc) ------------------------
+            hw = q[jnp.arange(NQ), head]
+            hf = hw & _FLOW_MASK
+            hh = (hw >> _HOP_SHIFT) & _HOP_MASK
+            nonempty = size > 0
 
-        lane_base = (jnp.arange(NQ) // (n_ch * n_vc)) * (n_ch * n_vc)
-        if adaptive:
-            # consume on destination arrival; next hop chosen live among
-            # the minimal alternates by downstream adaptive free space,
-            # escape lane (VC0 over the tree) as the safe fallback
-            dq = dstN[hf]
-            consume_q = nonempty & (node_q == dq)
-            cand_ch = jnp.clip(outch[node_q], 0, n_ch - 1)     # (NQ, D)
-            mm = minmask[ph, node_q, dq]
-            ok_cand = ((mm[:, None] >> jnp.arange(D)[None, :]) & 1) > 0
-            if faulted:
-                ok_cand = ok_cand & (alive[ph, cand_ch] > 0)
-            # free space of the queue the packet would actually join:
-            # its destination-bound adaptive VC on each candidate channel
-            vq = (1 + dq % (n_vc - 1))[:, None]
-            occ = size[lane_base[:, None] + cand_ch * n_vc + vq]
-            score = jnp.where(ok_cand, slots - occ, -1)
-            # rotate tie-breaks per (queue, cycle): equal scores would
-            # otherwise herd every packet at a node onto one alternate
-            rot = (jnp.arange(D)[None, :] + qrows[:, None] + i) % D
-            j = jnp.argmax(score * D + rot, axis=1)
-            best_ch = cand_ch[qrows, j]
-            has_cand = score[qrows, j] >= 0
-            # destination-bound adaptive VC: confines any one endpoint's
-            # backlog to a single VC per channel, so victim flows keep
-            # the other adaptive VCs (least-occupied selection was
-            # measured to level-fill every VC with hotspot backlog and
-            # collapse total throughput well below the static tables)
-            bv = 1 + dq % (n_vc - 1)
-            # planned-path-first: a packet still on its static path keeps
-            # it while the destination-bound queue ahead has room -- the
-            # LP-balanced tables confine backlog to the same narrow cones
-            # static routing does -- and only overflows onto the freest
-            # minimal alternate (off-path and post-fault packets route
-            # fully adaptively)
-            my_ch = (qrows // n_vc) % n_ch
-            on_path = (hh <= lenm1[hf]) \
-                & (pvf[jnp.minimum(hptr[hf] + hh, H - 1)] // n_vc
-                   == my_ch)
-            chan_s = pvf[jnp.minimum(hptr[hf] + hh + 1, H - 1)] // n_vc
-            prim_occ = size[lane_base + chan_s * n_vc + bv]
-            best_occ = slots - score[qrows, j]    # slots + 1 when no cand
-            prim_take = on_path & ~consume_q & (prim_occ < slots) \
-                & (prim_occ <= best_occ + 4)
-            if faulted:
-                prim_take = prim_take & (alive[ph, chan_s] > 0)
-            use_esc = (vc_q == 0) | (stall >= patience) \
-                | (~has_cand & ~prim_take)
-            e_ch = esc[ph, node_q, dq]
-            nxt_ch = jnp.where(use_esc, e_ch,
-                               jnp.where(prim_take, chan_s, best_ch))
-            nxt_vc = jnp.where(use_esc, 0, bv)
-            valid = nxt_ch >= 0
-            if faulted:
-                valid = valid & (alive[ph, jnp.clip(nxt_ch, 0,
-                                                    n_ch - 1)] > 0)
-            tq = jnp.where(consume_q | ~valid, -1,
-                           lane_base
-                           + jnp.clip(nxt_ch, 0, n_ch - 1) * n_vc
-                           + nxt_vc)
-            fwd_ok = nonempty & ~consume_q & (tq >= 0) \
-                & (size[jnp.clip(tq, 0, NQ - 1)] < slots)
-        else:
-            consume_q = nonempty & (hh == lenm1[hf])
-            nxt = pvf[jnp.minimum(hptr[hf] + hh + 1, H - 1)]
-            tq = jnp.where(consume_q, -1, lane_base + nxt)
-            if faulted:
-                # dead next hop: the packet waits in place (and the
-                # watchdog eventually reports the wedged lane)
-                tq = jnp.where(alive[ph, nxt // n_vc] > 0, tq, -1)
+            lane_base = (jnp.arange(NQ) // (n_ch * n_vc)) * (n_ch * n_vc)
+            if adaptive:
+                # consume on destination arrival; next hop chosen live among
+                # the minimal alternates by downstream adaptive free space,
+                # escape lane (VC0 over the tree) as the safe fallback
+                dq = dstN[hf]
+                consume_q = nonempty & (node_q == dq)
+                cand_ch = jnp.clip(outch[node_q], 0, n_ch - 1)     # (NQ, D)
+                mm = minmask[ph, node_q, dq]
+                ok_cand = ((mm[:, None] >> jnp.arange(D)[None, :]) & 1) > 0
+                if faulted:
+                    ok_cand = ok_cand & (alive[ph, cand_ch] > 0)
+                # free space of the queue the packet would actually join:
+                # its destination-bound adaptive VC on each candidate channel
+                vq = (1 + dq % (n_vc - 1))[:, None]
+                occ = size[lane_base[:, None] + cand_ch * n_vc + vq]
+                score = jnp.where(ok_cand, slots - occ, -1)
+                # rotate tie-breaks per (queue, cycle): equal scores would
+                # otherwise herd every packet at a node onto one alternate
+                rot = (jnp.arange(D)[None, :] + qrows[:, None] + i) % D
+                j = jnp.argmax(score * D + rot, axis=1)
+                best_ch = cand_ch[qrows, j]
+                has_cand = score[qrows, j] >= 0
+                # destination-bound adaptive VC: confines any one endpoint's
+                # backlog to a single VC per channel, so victim flows keep
+                # the other adaptive VCs (least-occupied selection was
+                # measured to level-fill every VC with hotspot backlog and
+                # collapse total throughput well below the static tables)
+                bv = 1 + dq % (n_vc - 1)
+                # planned-path-first: a packet still on its static path keeps
+                # it while the destination-bound queue ahead has room -- the
+                # LP-balanced tables confine backlog to the same narrow cones
+                # static routing does -- and only overflows onto the freest
+                # minimal alternate (off-path and post-fault packets route
+                # fully adaptively)
+                my_ch = (qrows // n_vc) % n_ch
+                on_path = (hh <= lenm1[hf]) \
+                    & (pvf[jnp.minimum(hptr[hf] + hh, H - 1)] // n_vc
+                       == my_ch)
+                chan_s = pvf[jnp.minimum(hptr[hf] + hh + 1, H - 1)] // n_vc
+                prim_occ = size[lane_base + chan_s * n_vc + bv]
+                best_occ = slots - score[qrows, j]    # slots + 1 when no cand
+                prim_take = on_path & ~consume_q & (prim_occ < slots) \
+                    & (prim_occ <= best_occ + 4)
+                if faulted:
+                    prim_take = prim_take & (alive[ph, chan_s] > 0)
+                use_esc = (vc_q == 0) | (stall >= patience) \
+                    | (~has_cand & ~prim_take)
+                e_ch = esc[ph, node_q, dq]
+                nxt_ch = jnp.where(use_esc, e_ch,
+                                   jnp.where(prim_take, chan_s, best_ch))
+                nxt_vc = jnp.where(use_esc, 0, bv)
+                valid = nxt_ch >= 0
+                if faulted:
+                    valid = valid & (alive[ph, jnp.clip(nxt_ch, 0,
+                                                        n_ch - 1)] > 0)
+                tq = jnp.where(consume_q | ~valid, -1,
+                               lane_base
+                               + jnp.clip(nxt_ch, 0, n_ch - 1) * n_vc
+                               + nxt_vc)
                 fwd_ok = nonempty & ~consume_q & (tq >= 0) \
                     & (size[jnp.clip(tq, 0, NQ - 1)] < slots)
             else:
-                fwd_ok = nonempty & ~consume_q \
-                    & (size[jnp.clip(tq, 0, NQ - 1)] < slots)
-        eligible = consume_q | fwd_ok                   # per (c, v)
+                consume_q = nonempty & (hh == lenm1[hf])
+                nxt = pvf[jnp.minimum(hptr[hf] + hh + 1, H - 1)]
+                tq = jnp.where(consume_q, -1, lane_base + nxt)
+                if faulted:
+                    # dead next hop: the packet waits in place (and the
+                    # watchdog eventually reports the wedged lane)
+                    tq = jnp.where(alive[ph, nxt // n_vc] > 0, tq, -1)
+                    fwd_ok = nonempty & ~consume_q & (tq >= 0) \
+                        & (size[jnp.clip(tq, 0, NQ - 1)] < slots)
+                else:
+                    fwd_ok = nonempty & ~consume_q \
+                        & (size[jnp.clip(tq, 0, NQ - 1)] < slots)
+            eligible = consume_q | fwd_ok                   # per (c, v)
 
-        # ---- round-robin arbitration: one vc per channel ------------------
-        # multi-flit packets occupy the link for `flits` cycles
-        eligible = eligible & jnp.repeat(busy == 0, n_vc)
-        elig_cv = eligible.reshape(C, n_vc)
-        offs = (rr[:, None] + jnp.arange(n_vc)[None, :]) % n_vc
-        pri = jnp.take_along_axis(elig_cv, offs, axis=1)
-        first = jnp.argmax(pri, axis=1)
-        any_e = pri.any(axis=1)
-        win_v = (rr + first) % n_vc
-        win_q = jnp.arange(C) * n_vc + win_v             # (C,)
-        win_valid = any_e
-        rr = jnp.where(win_valid, (win_v + 1) % n_vc, rr)
+        with jax.named_scope("arbitrate"):
+            # ---- round-robin arbitration: one vc per channel ----------------
+            # multi-flit packets occupy the link for `flits` cycles
+            eligible = eligible & jnp.repeat(busy == 0, n_vc)
+            elig_cv = eligible.reshape(C, n_vc)
+            offs = (rr[:, None] + jnp.arange(n_vc)[None, :]) % n_vc
+            pri = jnp.take_along_axis(elig_cv, offs, axis=1)
+            first = jnp.argmax(pri, axis=1)
+            any_e = pri.any(axis=1)
+            win_v = (rr + first) % n_vc
+            win_q = jnp.arange(C) * n_vc + win_v             # (C,)
+            win_valid = any_e
+            rr = jnp.where(win_valid, (win_v + 1) % n_vc, rr)
 
-        w_word = hw[win_q]
-        w_tag = (w_word >> _TAG_SHIFT) & 1
-        w_consume = consume_q[win_q] & win_valid
-        w_target = jnp.where(win_valid & ~w_consume, tq[win_q], -1)
+            w_word = hw[win_q]
+            w_tag = (w_word >> _TAG_SHIFT) & 1
+            w_consume = consume_q[win_q] & win_valid
+            w_target = jnp.where(win_valid & ~w_consume, tq[win_q], -1)
 
-        # ---- crossbar constraint: one push per target queue per cycle ----
-        # (a router output accepts one packet from the crossbar per cycle;
-        # the lowest-id input wins, losers stall and retry next cycle).
-        # Targets never collide across lanes: flat queue ids are disjoint.
-        cand = win_valid & ~w_consume & (w_target >= 0)
-        tgt = jnp.clip(w_target, 0, NQ - 1)
-        first = jnp.full((NQ + 1,), C, jnp.int32) \
-            .at[jnp.where(cand, tgt, NQ)].min(jnp.arange(C, dtype=jnp.int32))
-        w_push = cand & (first[tgt] == jnp.arange(C))
-        w_pop = w_consume | w_push
-        busy = jnp.where(w_pop, flits - 1, jnp.maximum(busy - 1, 0))
+        with jax.named_scope("crossbar"):
+            # ---- crossbar constraint: one push per target queue per cycle ---
+            # (a router output accepts one packet from the crossbar per cycle;
+            # the lowest-id input wins, losers stall and retry next cycle).
+            # Targets never collide across lanes: flat queue ids are disjoint.
+            cand = win_valid & ~w_consume & (w_target >= 0)
+            tgt = jnp.clip(w_target, 0, NQ - 1)
+            first = jnp.full((NQ + 1,), C, jnp.int32) \
+                .at[jnp.where(cand, tgt, NQ)] \
+                .min(jnp.arange(C, dtype=jnp.int32))
+            w_push = cand & (first[tgt] == jnp.arange(C))
+            w_pop = w_consume | w_push
+            busy = jnp.where(w_pop, flits - 1, jnp.maximum(busy - 1, 0))
 
-        # ---- push slots ----------------------------------------------------
-        # post-pop (head + size) equals pre-pop (head + size): a pop moves
-        # head forward and shrinks size by one, so the tail slot is stable
-        p_slot = (head[tgt] + size[tgt]) % slots
-        if adaptive:
-            # adaptive paths are not length-bounded by the route table, so
-            # saturate the 6-bit hop field instead of wrapping into the tag
-            w_hh = (w_word >> _HOP_SHIFT) & _HOP_MASK
-            push_word = jnp.where(w_hh >= _HOP_MASK, w_word,
-                                  w_word + (1 << _HOP_SHIFT))
-        else:
-            push_word = w_word + (1 << _HOP_SHIFT)  # hop += 1, rest intact
-
-        # ---- injection: alias-sampled routed flow per source --------------
-        measure = i >= warmup
-        key, k1, k2, k3 = jax.random.split(key, 4)
-        if phased:
-            thr = (rates[:, None] * src_rate[phz][None, :]).reshape(N)
-            fp, fa = fprob[phz], falias[phz]
-        else:
-            thr, fp, fa = thresh, fprob, falias
-        if bursty:
-            on = ((i + phs) % period) < on_cycles
-            want = jax.random.uniform(k1, (N,)) \
-                < thr * jnp.where(on, g_on, g_off)
-        else:
-            want = jax.random.uniform(k1, (N,)) < thr
-        u1 = jax.random.uniform(k2, (N,))
-        dg = deg[srcs]
-        j = jnp.minimum((u1 * dg.astype(jnp.float32)).astype(jnp.int32),
-                        dg - 1)
-        f0 = src_ptr[srcs] + jnp.maximum(j, 0)
-        u2 = jax.random.uniform(k3, (N,))
-        fid = jnp.where(u2 < fp[f0], f0, fa[f0])
-        cv0 = pvf[hptr[fid]]
-        if adaptive or faulted:
-            ch0 = cv0 // n_vc
-            ok0 = (alive[ph, ch0] > 0) if faulted \
-                else jnp.ones((N,), bool)
+        with jax.named_scope("push"):
+            # ---- push slots -------------------------------------------------
+            # post-pop (head + size) equals pre-pop (head + size): a pop moves
+            # head forward and shrinks size by one, so the tail slot is stable
+            p_slot = (head[tgt] + size[tgt]) % slots
             if adaptive:
-                # the stored VC is a static-mode artifact: inject onto
-                # the planned channel's destination-bound adaptive VC
-                # (sources can always wait, so injection never needs the
-                # escape guarantee). Planned first hop dead: inject
-                # straight onto the escape tree; no escape route -> hold.
-                dstf = dstN[fid]
-                iv = 1 + dstf % (n_vc - 1)
-                e0 = esc[ph, srcs, dstf]
-                cv0 = jnp.where(ok0, ch0 * n_vc + iv,
-                                jnp.maximum(e0, 0) * n_vc)
-                ok0 = ok0 | (e0 >= 0)
-            iq = lane_q + cv0
-        else:
-            iq = lane_q + cv0
-        # queue iq was popped this cycle iff its channel's winner is iq
-        i_pop = (w_pop[iq // n_vc]
-                 & (win_q[iq // n_vc] == iq)).astype(jnp.int32)
-        # at most one push lands in iq this cycle (crossbar constraint)
-        i_push = (first[iq] < C).astype(jnp.int32)
-        has_space = size[iq] - i_pop + i_push < slots
-        inj = want & has_space & (dg > 0)
-        if adaptive or faulted:
-            inj = inj & ok0
-        i_slot = (head[iq] + size[iq] + i_push) % slots
-        inj_word = _pack_flow(fid, jnp.zeros((N,), jnp.int32),
-                              measure & inj)
+                # adaptive paths are not length-bounded by the route table, so
+                # saturate the 6-bit hop field instead of wrapping into the tag
+                w_hh = (w_word >> _HOP_SHIFT) & _HOP_MASK
+                push_word = jnp.where(w_hh >= _HOP_MASK, w_word,
+                                      w_word + (1 << _HOP_SHIFT))
+            else:
+                push_word = w_word + (1 << _HOP_SHIFT)  # hop += 1, rest intact
 
-        # ---- one fused scatter for pushes + injections --------------------
-        all_rows = jnp.concatenate([jnp.where(w_push, tgt, NQ),
-                                    jnp.where(inj, iq, NQ)])
-        all_slots = jnp.concatenate([p_slot, i_slot])
-        all_words = jnp.concatenate([push_word, inj_word])
-        q = q.at[all_rows, all_slots].set(all_words, mode="drop")
+        with jax.named_scope("inject"):
+            # ---- injection: alias-sampled routed flow per source ------------
+            measure = i >= warmup
+            key, k1, k2, k3 = jax.random.split(key, 4)
+            if phased:
+                thr = (rates[:, None] * src_rate[phz][None, :]).reshape(N)
+                fp, fa = fprob[phz], falias[phz]
+            else:
+                thr, fp, fa = thresh, fprob, falias
+            if bursty:
+                on = ((i + phs) % period) < on_cycles
+                want = jax.random.uniform(k1, (N,)) \
+                    < thr * jnp.where(on, g_on, g_off)
+            else:
+                want = jax.random.uniform(k1, (N,)) < thr
+            u1 = jax.random.uniform(k2, (N,))
+            dg = deg[srcs]
+            j = jnp.minimum((u1 * dg.astype(jnp.float32)).astype(jnp.int32),
+                            dg - 1)
+            f0 = src_ptr[srcs] + jnp.maximum(j, 0)
+            u2 = jax.random.uniform(k3, (N,))
+            fid = jnp.where(u2 < fp[f0], f0, fa[f0])
+            cv0 = pvf[hptr[fid]]
+            if adaptive or faulted:
+                ch0 = cv0 // n_vc
+                ok0 = (alive[ph, ch0] > 0) if faulted \
+                    else jnp.ones((N,), bool)
+                if adaptive:
+                    # the stored VC is a static-mode artifact: inject onto
+                    # the planned channel's destination-bound adaptive VC
+                    # (sources can always wait, so injection never needs the
+                    # escape guarantee). Planned first hop dead: inject
+                    # straight onto the escape tree; no escape route -> hold.
+                    dstf = dstN[fid]
+                    iv = 1 + dstf % (n_vc - 1)
+                    e0 = esc[ph, srcs, dstf]
+                    cv0 = jnp.where(ok0, ch0 * n_vc + iv,
+                                    jnp.maximum(e0, 0) * n_vc)
+                    ok0 = ok0 | (e0 >= 0)
+                iq = lane_q + cv0
+            else:
+                iq = lane_q + cv0
+            # queue iq was popped this cycle iff its channel's winner is iq
+            i_pop = (w_pop[iq // n_vc]
+                     & (win_q[iq // n_vc] == iq)).astype(jnp.int32)
+            # at most one push lands in iq this cycle (crossbar constraint)
+            i_push = (first[iq] < C).astype(jnp.int32)
+            has_space = size[iq] - i_pop + i_push < slots
+            inj = want & has_space & (dg > 0)
+            if adaptive or faulted:
+                inj = inj & ok0
+            i_slot = (head[iq] + size[iq] + i_push) % slots
+            inj_word = _pack_flow(fid, jnp.zeros((N,), jnp.int32),
+                                  measure & inj)
 
-        # ---- one fused scatter-add for every size delta, one for heads ----
-        popq = jnp.where(w_pop, win_q, NQ)
-        d_rows = jnp.concatenate([popq, all_rows])
-        d_vals = jnp.concatenate([jnp.full((C,), -1, jnp.int32),
-                                  jnp.ones((C + N,), jnp.int32)])
-        size = size.at[d_rows].add(d_vals, mode="drop")
-        head = head.at[popq].add(1, mode="drop") % slots
+        with jax.named_scope("scatter"):
+            # ---- one fused scatter for pushes + injections ------------------
+            all_rows = jnp.concatenate([jnp.where(w_push, tgt, NQ),
+                                        jnp.where(inj, iq, NQ)])
+            all_slots = jnp.concatenate([p_slot, i_slot])
+            all_words = jnp.concatenate([push_word, inj_word])
+            q = q.at[all_rows, all_slots].set(all_words, mode="drop")
 
-        meas = jnp.where(measure, 1, 0)
-        cons_lane = w_consume.reshape(R, n_ch).sum(axis=1)
-        inj_lane = inj.reshape(R, n).sum(axis=1)
-        offered = offered + meas * want.reshape(R, n).sum(axis=1)
-        accepted = accepted + meas * inj_lane
-        tagged = tagged + (w_consume & (w_tag == 1)).reshape(
-            R, n_ch).sum(axis=1)
-        consumed_meas = consumed_meas + meas * cons_lane
-        consumed = consumed + cons_lane
-        injected = injected + inj_lane
+            # ---- one fused scatter-add for every size delta, one for heads --
+            popq = jnp.where(w_pop, win_q, NQ)
+            d_rows = jnp.concatenate([popq, all_rows])
+            d_vals = jnp.concatenate([jnp.full((C,), -1, jnp.int32),
+                                      jnp.ones((C + N,), jnp.int32)])
+            size = size.at[d_rows].add(d_vals, mode="drop")
+            head = head.at[popq].add(1, mode="drop") % slots
 
-        if T:
-            # per-(lane, tenant) accounting; flow -> tenant is static
-            # (`tof`), so attribution costs two gathers and two
-            # scatter-adds, no extra RNG
-            t_w = tof[hf[win_q]]
-            ok_w = w_consume & (t_w >= 0)
-            rowc = (jnp.arange(C) // n_ch) * T + jnp.clip(t_w, 0, T - 1)
-            cons_t = cons_t.at[rowc].add(ok_w.astype(jnp.int32))
-            consm_t = consm_t.at[rowc].add(
-                (ok_w & measure).astype(jnp.int32))
-            t_i = tof[fid]
-            rowi = (jnp.arange(N) // n) * T + jnp.clip(t_i, 0, T - 1)
-            inj_t = inj_t.at[rowi].add(
-                (inj & (t_i >= 0)).astype(jnp.int32))
-
-        if adaptive:
-            # per-queue persistent-stall counter (drives escape diversion)
-            popped = w_pop[qrows // n_vc] & (win_q[qrows // n_vc] == qrows)
-            stall = jnp.where(nonempty & ~popped, stall + 1, 0)
-            # escape diversions: pushes that land on VC0 from a VC >= 1
-            escaped = escaped + (w_push & (tgt % n_vc == 0)
-                                 & (win_q % n_vc != 0)).reshape(
+        with jax.named_scope("counters"):
+            meas = jnp.where(measure, 1, 0)
+            cons_lane = w_consume.reshape(R, n_ch).sum(axis=1)
+            inj_lane = inj.reshape(R, n).sum(axis=1)
+            offered = offered + meas * want.reshape(R, n).sum(axis=1)
+            accepted = accepted + meas * inj_lane
+            tagged = tagged + (w_consume & (w_tag == 1)).reshape(
                 R, n_ch).sum(axis=1)
+            consumed_meas = consumed_meas + meas * cons_lane
+            consumed = consumed + cons_lane
+            injected = injected + inj_lane
 
-        # ---- watchdog: lanes with traffic but zero forward progress -------
-        pop_lane = w_pop.reshape(R, n_ch).sum(axis=1)
-        progress = (pop_lane > 0) | (inj_lane > 0)
-        wstall = jnp.where((injected - consumed > 0) & ~progress,
-                           wstall + 1, 0)
-        stalled_at = jnp.where((wstall >= watchdog) & (stalled_at < 0),
-                               i, stalled_at)
+            if T:
+                # per-(lane, tenant) accounting; flow -> tenant is static
+                # (`tof`), so attribution costs two gathers and two
+                # scatter-adds, no extra RNG
+                t_w = tof[hf[win_q]]
+                ok_w = w_consume & (t_w >= 0)
+                rowc = (jnp.arange(C) // n_ch) * T + jnp.clip(t_w, 0, T - 1)
+                cons_t = cons_t.at[rowc].add(ok_w.astype(jnp.int32))
+                consm_t = consm_t.at[rowc].add(
+                    (ok_w & measure).astype(jnp.int32))
+                t_i = tof[fid]
+                rowi = (jnp.arange(N) // n) * T + jnp.clip(t_i, 0, T - 1)
+                inj_t = inj_t.at[rowi].add(
+                    (inj & (t_i >= 0)).astype(jnp.int32))
+
+            if adaptive:
+                # per-queue persistent-stall counter (drives escape diversion)
+                popped = w_pop[qrows // n_vc] & (win_q[qrows // n_vc] == qrows)
+                stall = jnp.where(nonempty & ~popped, stall + 1, 0)
+                # escape diversions: pushes that land on VC0 from a VC >= 1
+                escaped = escaped + (w_push & (tgt % n_vc == 0)
+                                     & (win_q % n_vc != 0)).reshape(
+                    R, n_ch).sum(axis=1)
+
+        with jax.named_scope("watchdog"):
+            # ---- watchdog: lanes with traffic but zero forward progress -----
+            pop_lane = w_pop.reshape(R, n_ch).sum(axis=1)
+            hops = hops + pop_lane          # packet-hops: pops of any queue
+            progress = (pop_lane > 0) | (inj_lane > 0)
+            wstall = jnp.where((injected - consumed > 0) & ~progress,
+                               wstall + 1, 0)
+            stalled_at = jnp.where((wstall >= watchdog) & (stalled_at < 0),
+                                   i, stalled_at)
         return (i + 1, q, head, size, rr, busy, key, stall, wstall,
                 stalled_at,
                 (offered, accepted, tagged, consumed_meas, consumed,
-                 injected, escaped, inj_t, cons_t, consm_t))
+                 injected, escaped, hops, inj_t, cons_t, consm_t))
 
-    stats0 = (jnp.zeros((R,), jnp.int32),) * 7 \
+    stats0 = (jnp.zeros((R,), jnp.int32),) * 8 \
         + (jnp.zeros((R * T,), jnp.int32),) * 3
     stall0 = jnp.zeros((NQ if adaptive else 1,), jnp.int32)
     carry = (jnp.int32(0), q, head, size, rr, busy, key, stall0,
@@ -514,7 +525,7 @@ def _sweep_csr(ch_dst, pvf, hptr, lenm1, dstN, src_ptr, deg, fprob, falias,
     q, head, size = carry[1], carry[2], carry[3]
     stalled_at = carry[9]
     (offered, accepted, tagged, consumed_meas, consumed, injected,
-     escaped, inj_t, cons_t, consm_t) = carry[-1]
+     escaped, hops, inj_t, cons_t, consm_t) = carry[-1]
     if T:
         # per-tenant end-of-run occupancy from the final ring buffers:
         # slot j of queue r holds a live word iff (j - head) % slots
@@ -529,7 +540,7 @@ def _sweep_csr(ch_dst, pvf, hptr, lenm1, dstN, src_ptr, deg, fprob, falias,
     else:
         infl_t = jnp.zeros((0,), jnp.int32)
     return (offered, accepted, tagged, consumed_meas, consumed, injected,
-            escaped, size.reshape(R, -1).sum(axis=1), stalled_at,
+            escaped, hops, size.reshape(R, -1).sum(axis=1), stalled_at,
             inj_t, cons_t, consm_t, infl_t, carry[0])
 
 
@@ -583,214 +594,224 @@ def _sweep_dense(ch_dst, pv, fdst, src_ptr, deg, fprob, falias,
         i, q, head, size, rr, busy, key, stall, wstall, stalled_at, \
             stats = carry
         (offered, accepted, tagged, consumed_meas, consumed, injected,
-         escaped, inj_t, cons_t, consm_t) = stats
+         escaped, hops, inj_t, cons_t, consm_t) = stats
         ph = (i >= t_fault).astype(jnp.int32) if faulted else 0
         phz = phase_of[i % p_period] if phased else 0
 
-        hw = q[jnp.arange(NQ), head]
-        hs = hw & _FIELD_MASK
-        hd = (hw >> _DST_SHIFT) & _FIELD_MASK
-        hh = (hw >> _HOP_SHIFT) & _HOP_MASK
-        nonempty = size > 0
+        with jax.named_scope("route"):
+            hw = q[jnp.arange(NQ), head]
+            hs = hw & _FIELD_MASK
+            hd = (hw >> _DST_SHIFT) & _FIELD_MASK
+            hh = (hw >> _HOP_SHIFT) & _HOP_MASK
+            nonempty = size > 0
 
-        consume_q = nonempty & (arrive_node == hd)
-        lane_base = (jnp.arange(NQ) // (n_ch * n_vc)) * (n_ch * n_vc)
-        if adaptive:
-            dq = hd
-            cand_ch = jnp.clip(outch[arrive_node], 0, n_ch - 1)
-            mm = minmask[ph, arrive_node, dq]
-            ok_cand = ((mm[:, None] >> jnp.arange(D)[None, :]) & 1) > 0
-            if faulted:
-                ok_cand = ok_cand & (alive[ph, cand_ch] > 0)
-            # free space of the queue the packet would actually join:
-            # its destination-bound adaptive VC on each candidate channel
-            vq = (1 + dq % (n_vc - 1))[:, None]
-            occ = size[lane_base[:, None] + cand_ch * n_vc + vq]
-            score = jnp.where(ok_cand, slots - occ, -1)
-            rot = (jnp.arange(D)[None, :] + qrows[:, None] + i) % D
-            j = jnp.argmax(score * D + rot, axis=1)    # rotating tie-break
-            best_ch = cand_ch[qrows, j]
-            has_cand = score[qrows, j] >= 0
-            bv = 1 + dq % (n_vc - 1)    # destination-bound VC (see CSR)
-            # planned-path-first, mirroring the CSR kernel
-            my_ch = (qrows // n_vc) % n_ch
-            pcur = pv[hs, hd, hh]
-            on_path = (pcur >= 0) & (pcur // n_vc == my_ch)
-            pnxt = pv[hs, hd, hh + 1]
-            chan_s = jnp.clip(pnxt, 0, n_ch * n_vc - 1) // n_vc
-            prim_occ = size[lane_base + chan_s * n_vc + bv]
-            best_occ = slots - score[qrows, j]    # slots + 1 when no cand
-            prim_take = on_path & (pnxt >= 0) & ~consume_q & (prim_occ < slots) \
-                & (prim_occ <= best_occ + 4)
-            if faulted:
-                prim_take = prim_take & (alive[ph, chan_s] > 0)
-            use_esc = (vc_q == 0) | (stall >= patience) \
-                | (~has_cand & ~prim_take)
-            e_ch = esc[ph, arrive_node, dq]
-            nxt_ch = jnp.where(use_esc, e_ch,
-                               jnp.where(prim_take, chan_s, best_ch))
-            nxt_vc = jnp.where(use_esc, 0, bv)
-            valid = nxt_ch >= 0
-            if faulted:
-                valid = valid & (alive[ph, jnp.clip(nxt_ch, 0,
-                                                    n_ch - 1)] > 0)
-            tq = jnp.where(consume_q | ~valid, -1,
-                           lane_base
-                           + jnp.clip(nxt_ch, 0, n_ch - 1) * n_vc
-                           + nxt_vc)
-            fwd_ok = nonempty & ~consume_q & (tq >= 0) \
-                & (size[jnp.clip(tq, 0, NQ - 1)] < slots)
-        else:
-            # pv packs channel * n_vc + vc per hop: one gather for both
-            nxt = pv[hs, hd, hh + 1]
-            tq = jnp.where(consume_q, -1, lane_base + nxt)
-            if faulted:
-                tq = jnp.where(alive[ph, nxt // n_vc] > 0, tq, -1)
+            consume_q = nonempty & (arrive_node == hd)
+            lane_base = (jnp.arange(NQ) // (n_ch * n_vc)) * (n_ch * n_vc)
+            if adaptive:
+                dq = hd
+                cand_ch = jnp.clip(outch[arrive_node], 0, n_ch - 1)
+                mm = minmask[ph, arrive_node, dq]
+                ok_cand = ((mm[:, None] >> jnp.arange(D)[None, :]) & 1) > 0
+                if faulted:
+                    ok_cand = ok_cand & (alive[ph, cand_ch] > 0)
+                # free space of the queue the packet would actually join:
+                # its destination-bound adaptive VC on each candidate channel
+                vq = (1 + dq % (n_vc - 1))[:, None]
+                occ = size[lane_base[:, None] + cand_ch * n_vc + vq]
+                score = jnp.where(ok_cand, slots - occ, -1)
+                rot = (jnp.arange(D)[None, :] + qrows[:, None] + i) % D
+                j = jnp.argmax(score * D + rot, axis=1)    # rotating tie-break
+                best_ch = cand_ch[qrows, j]
+                has_cand = score[qrows, j] >= 0
+                bv = 1 + dq % (n_vc - 1)    # destination-bound VC (see CSR)
+                # planned-path-first, mirroring the CSR kernel
+                my_ch = (qrows // n_vc) % n_ch
+                pcur = pv[hs, hd, hh]
+                on_path = (pcur >= 0) & (pcur // n_vc == my_ch)
+                pnxt = pv[hs, hd, hh + 1]
+                chan_s = jnp.clip(pnxt, 0, n_ch * n_vc - 1) // n_vc
+                prim_occ = size[lane_base + chan_s * n_vc + bv]
+                best_occ = slots - score[qrows, j]    # slots + 1 when no cand
+                prim_take = on_path & (pnxt >= 0) & ~consume_q \
+                    & (prim_occ < slots) & (prim_occ <= best_occ + 4)
+                if faulted:
+                    prim_take = prim_take & (alive[ph, chan_s] > 0)
+                use_esc = (vc_q == 0) | (stall >= patience) \
+                    | (~has_cand & ~prim_take)
+                e_ch = esc[ph, arrive_node, dq]
+                nxt_ch = jnp.where(use_esc, e_ch,
+                                   jnp.where(prim_take, chan_s, best_ch))
+                nxt_vc = jnp.where(use_esc, 0, bv)
+                valid = nxt_ch >= 0
+                if faulted:
+                    valid = valid & (alive[ph, jnp.clip(nxt_ch, 0,
+                                                        n_ch - 1)] > 0)
+                tq = jnp.where(consume_q | ~valid, -1,
+                               lane_base
+                               + jnp.clip(nxt_ch, 0, n_ch - 1) * n_vc
+                               + nxt_vc)
                 fwd_ok = nonempty & ~consume_q & (tq >= 0) \
                     & (size[jnp.clip(tq, 0, NQ - 1)] < slots)
             else:
-                fwd_ok = nonempty & ~consume_q \
-                    & (size[jnp.clip(tq, 0, NQ - 1)] < slots)
-        eligible = consume_q | fwd_ok
+                # pv packs channel * n_vc + vc per hop: one gather for both
+                nxt = pv[hs, hd, hh + 1]
+                tq = jnp.where(consume_q, -1, lane_base + nxt)
+                if faulted:
+                    tq = jnp.where(alive[ph, nxt // n_vc] > 0, tq, -1)
+                    fwd_ok = nonempty & ~consume_q & (tq >= 0) \
+                        & (size[jnp.clip(tq, 0, NQ - 1)] < slots)
+                else:
+                    fwd_ok = nonempty & ~consume_q \
+                        & (size[jnp.clip(tq, 0, NQ - 1)] < slots)
+            eligible = consume_q | fwd_ok
 
-        eligible = eligible & jnp.repeat(busy == 0, n_vc)
-        elig_cv = eligible.reshape(C, n_vc)
-        offs = (rr[:, None] + jnp.arange(n_vc)[None, :]) % n_vc
-        pri = jnp.take_along_axis(elig_cv, offs, axis=1)
-        first = jnp.argmax(pri, axis=1)
-        any_e = pri.any(axis=1)
-        win_v = (rr + first) % n_vc
-        win_q = jnp.arange(C) * n_vc + win_v
-        win_valid = any_e
-        rr = jnp.where(win_valid, (win_v + 1) % n_vc, rr)
+        with jax.named_scope("arbitrate"):
+            eligible = eligible & jnp.repeat(busy == 0, n_vc)
+            elig_cv = eligible.reshape(C, n_vc)
+            offs = (rr[:, None] + jnp.arange(n_vc)[None, :]) % n_vc
+            pri = jnp.take_along_axis(elig_cv, offs, axis=1)
+            first = jnp.argmax(pri, axis=1)
+            any_e = pri.any(axis=1)
+            win_v = (rr + first) % n_vc
+            win_q = jnp.arange(C) * n_vc + win_v
+            win_valid = any_e
+            rr = jnp.where(win_valid, (win_v + 1) % n_vc, rr)
 
-        w_word = hw[win_q]
-        w_tag = (w_word >> _TAG_SHIFT) & 1
-        w_consume = consume_q[win_q] & win_valid
-        w_target = jnp.where(win_valid & ~w_consume, tq[win_q], -1)
+            w_word = hw[win_q]
+            w_tag = (w_word >> _TAG_SHIFT) & 1
+            w_consume = consume_q[win_q] & win_valid
+            w_target = jnp.where(win_valid & ~w_consume, tq[win_q], -1)
 
-        cand = win_valid & ~w_consume & (w_target >= 0)
-        tgt = jnp.clip(w_target, 0, NQ - 1)
-        first = jnp.full((NQ + 1,), C, jnp.int32) \
-            .at[jnp.where(cand, tgt, NQ)].min(jnp.arange(C, dtype=jnp.int32))
-        w_push = cand & (first[tgt] == jnp.arange(C))
-        w_pop = w_consume | w_push
-        busy = jnp.where(w_pop, flits - 1, jnp.maximum(busy - 1, 0))
+        with jax.named_scope("crossbar"):
+            cand = win_valid & ~w_consume & (w_target >= 0)
+            tgt = jnp.clip(w_target, 0, NQ - 1)
+            first = jnp.full((NQ + 1,), C, jnp.int32) \
+                .at[jnp.where(cand, tgt, NQ)] \
+                .min(jnp.arange(C, dtype=jnp.int32))
+            w_push = cand & (first[tgt] == jnp.arange(C))
+            w_pop = w_consume | w_push
+            busy = jnp.where(w_pop, flits - 1, jnp.maximum(busy - 1, 0))
 
-        p_slot = (head[tgt] + size[tgt]) % slots
-        if adaptive:
-            w_hh = (w_word >> _HOP_SHIFT) & _HOP_MASK
-            push_word = jnp.where(w_hh >= _HOP_MASK, w_word,
-                                  w_word + (1 << _HOP_SHIFT))
-        else:
-            push_word = w_word + (1 << _HOP_SHIFT)
-
-        measure = i >= warmup
-        key, k1, k2, k3 = jax.random.split(key, 4)
-        if phased:
-            thr = (rates[:, None] * src_rate[phz][None, :]).reshape(N)
-            fp, fa = fprob[phz], falias[phz]
-        else:
-            thr, fp, fa = thresh, fprob, falias
-        if bursty:
-            on = ((i + phs) % period) < on_cycles
-            want = jax.random.uniform(k1, (N,)) \
-                < thr * jnp.where(on, g_on, g_off)
-        else:
-            want = jax.random.uniform(k1, (N,)) < thr
-        u1 = jax.random.uniform(k2, (N,))
-        dg = deg[srcs]
-        j = jnp.minimum((u1 * dg.astype(jnp.float32)).astype(jnp.int32),
-                        dg - 1)
-        f0 = src_ptr[srcs] + jnp.maximum(j, 0)
-        u2 = jax.random.uniform(k3, (N,))
-        fid = jnp.where(u2 < fp[f0], f0, fa[f0])
-        dsts = fdst[fid]
-        cv0 = pv[srcs, dsts, 0]
-        if adaptive or faulted:
-            ch0 = jnp.clip(cv0, 0, n_ch * n_vc - 1) // n_vc
-            ok0 = (alive[ph, ch0] > 0) if faulted \
-                else jnp.ones((N,), bool)
+        with jax.named_scope("push"):
+            p_slot = (head[tgt] + size[tgt]) % slots
             if adaptive:
-                iv = 1 + dsts % (n_vc - 1)
-                e0 = esc[ph, srcs, dsts]
-                cv0 = jnp.where(ok0, ch0 * n_vc + iv,
-                                jnp.maximum(e0, 0) * n_vc)
-                ok0 = ok0 | (e0 >= 0)
-            iq = lane_q + jnp.clip(cv0, 0, n_ch * n_vc - 1)
-        else:
-            iq = lane_q + jnp.clip(cv0, 0, n_ch * n_vc - 1)
-        i_pop = (w_pop[iq // n_vc]
-                 & (win_q[iq // n_vc] == iq)).astype(jnp.int32)
-        i_push = (first[iq] < C).astype(jnp.int32)
-        has_space = size[iq] - i_pop + i_push < slots
-        inj = want & has_space & (dg > 0)
-        if adaptive or faulted:
-            inj = inj & ok0
-        i_slot = (head[iq] + size[iq] + i_push) % slots
-        inj_word = _pack(srcs, dsts, jnp.zeros((N,), jnp.int32),
-                         measure & inj)
+                w_hh = (w_word >> _HOP_SHIFT) & _HOP_MASK
+                push_word = jnp.where(w_hh >= _HOP_MASK, w_word,
+                                      w_word + (1 << _HOP_SHIFT))
+            else:
+                push_word = w_word + (1 << _HOP_SHIFT)
 
-        all_rows = jnp.concatenate([jnp.where(w_push, tgt, NQ),
-                                    jnp.where(inj, iq, NQ)])
-        all_slots = jnp.concatenate([p_slot, i_slot])
-        all_words = jnp.concatenate([push_word, inj_word])
-        q = q.at[all_rows, all_slots].set(all_words, mode="drop")
+        with jax.named_scope("inject"):
+            measure = i >= warmup
+            key, k1, k2, k3 = jax.random.split(key, 4)
+            if phased:
+                thr = (rates[:, None] * src_rate[phz][None, :]).reshape(N)
+                fp, fa = fprob[phz], falias[phz]
+            else:
+                thr, fp, fa = thresh, fprob, falias
+            if bursty:
+                on = ((i + phs) % period) < on_cycles
+                want = jax.random.uniform(k1, (N,)) \
+                    < thr * jnp.where(on, g_on, g_off)
+            else:
+                want = jax.random.uniform(k1, (N,)) < thr
+            u1 = jax.random.uniform(k2, (N,))
+            dg = deg[srcs]
+            j = jnp.minimum((u1 * dg.astype(jnp.float32)).astype(jnp.int32),
+                            dg - 1)
+            f0 = src_ptr[srcs] + jnp.maximum(j, 0)
+            u2 = jax.random.uniform(k3, (N,))
+            fid = jnp.where(u2 < fp[f0], f0, fa[f0])
+            dsts = fdst[fid]
+            cv0 = pv[srcs, dsts, 0]
+            if adaptive or faulted:
+                ch0 = jnp.clip(cv0, 0, n_ch * n_vc - 1) // n_vc
+                ok0 = (alive[ph, ch0] > 0) if faulted \
+                    else jnp.ones((N,), bool)
+                if adaptive:
+                    iv = 1 + dsts % (n_vc - 1)
+                    e0 = esc[ph, srcs, dsts]
+                    cv0 = jnp.where(ok0, ch0 * n_vc + iv,
+                                    jnp.maximum(e0, 0) * n_vc)
+                    ok0 = ok0 | (e0 >= 0)
+                iq = lane_q + jnp.clip(cv0, 0, n_ch * n_vc - 1)
+            else:
+                iq = lane_q + jnp.clip(cv0, 0, n_ch * n_vc - 1)
+            i_pop = (w_pop[iq // n_vc]
+                     & (win_q[iq // n_vc] == iq)).astype(jnp.int32)
+            i_push = (first[iq] < C).astype(jnp.int32)
+            has_space = size[iq] - i_pop + i_push < slots
+            inj = want & has_space & (dg > 0)
+            if adaptive or faulted:
+                inj = inj & ok0
+            i_slot = (head[iq] + size[iq] + i_push) % slots
+            inj_word = _pack(srcs, dsts, jnp.zeros((N,), jnp.int32),
+                             measure & inj)
 
-        popq = jnp.where(w_pop, win_q, NQ)
-        d_rows = jnp.concatenate([popq, all_rows])
-        d_vals = jnp.concatenate([jnp.full((C,), -1, jnp.int32),
-                                  jnp.ones((C + N,), jnp.int32)])
-        size = size.at[d_rows].add(d_vals, mode="drop")
-        head = head.at[popq].add(1, mode="drop") % slots
+        with jax.named_scope("scatter"):
+            all_rows = jnp.concatenate([jnp.where(w_push, tgt, NQ),
+                                        jnp.where(inj, iq, NQ)])
+            all_slots = jnp.concatenate([p_slot, i_slot])
+            all_words = jnp.concatenate([push_word, inj_word])
+            q = q.at[all_rows, all_slots].set(all_words, mode="drop")
 
-        meas = jnp.where(measure, 1, 0)
-        cons_lane = w_consume.reshape(R, n_ch).sum(axis=1)
-        inj_lane = inj.reshape(R, n).sum(axis=1)
-        offered = offered + meas * want.reshape(R, n).sum(axis=1)
-        accepted = accepted + meas * inj_lane
-        tagged = tagged + (w_consume & (w_tag == 1)).reshape(
-            R, n_ch).sum(axis=1)
-        consumed_meas = consumed_meas + meas * cons_lane
-        consumed = consumed + cons_lane
-        injected = injected + inj_lane
+            popq = jnp.where(w_pop, win_q, NQ)
+            d_rows = jnp.concatenate([popq, all_rows])
+            d_vals = jnp.concatenate([jnp.full((C,), -1, jnp.int32),
+                                      jnp.ones((C + N,), jnp.int32)])
+            size = size.at[d_rows].add(d_vals, mode="drop")
+            head = head.at[popq].add(1, mode="drop") % slots
 
-        if T:
-            # dense words carry (src, dst): attribute via the pair map
-            # (tof[fid] == tmap[srcs, dsts] by construction, so the CSR
-            # kernel's counters stay bit-identical)
-            ws = w_word & _FIELD_MASK
-            wd = (w_word >> _DST_SHIFT) & _FIELD_MASK
-            t_w = tmap[ws, wd]
-            ok_w = w_consume & (t_w >= 0)
-            rowc = (jnp.arange(C) // n_ch) * T + jnp.clip(t_w, 0, T - 1)
-            cons_t = cons_t.at[rowc].add(ok_w.astype(jnp.int32))
-            consm_t = consm_t.at[rowc].add(
-                (ok_w & measure).astype(jnp.int32))
-            t_i = tof[fid]
-            rowi = (jnp.arange(N) // n) * T + jnp.clip(t_i, 0, T - 1)
-            inj_t = inj_t.at[rowi].add(
-                (inj & (t_i >= 0)).astype(jnp.int32))
-
-        if adaptive:
-            popped = w_pop[qrows // n_vc] & (win_q[qrows // n_vc] == qrows)
-            stall = jnp.where(nonempty & ~popped, stall + 1, 0)
-            escaped = escaped + (w_push & (tgt % n_vc == 0)
-                                 & (win_q % n_vc != 0)).reshape(
+        with jax.named_scope("counters"):
+            meas = jnp.where(measure, 1, 0)
+            cons_lane = w_consume.reshape(R, n_ch).sum(axis=1)
+            inj_lane = inj.reshape(R, n).sum(axis=1)
+            offered = offered + meas * want.reshape(R, n).sum(axis=1)
+            accepted = accepted + meas * inj_lane
+            tagged = tagged + (w_consume & (w_tag == 1)).reshape(
                 R, n_ch).sum(axis=1)
+            consumed_meas = consumed_meas + meas * cons_lane
+            consumed = consumed + cons_lane
+            injected = injected + inj_lane
 
-        pop_lane = w_pop.reshape(R, n_ch).sum(axis=1)
-        progress = (pop_lane > 0) | (inj_lane > 0)
-        wstall = jnp.where((injected - consumed > 0) & ~progress,
-                           wstall + 1, 0)
-        stalled_at = jnp.where((wstall >= watchdog) & (stalled_at < 0),
-                               i, stalled_at)
+            if T:
+                # dense words carry (src, dst): attribute via the pair map
+                # (tof[fid] == tmap[srcs, dsts] by construction, so the CSR
+                # kernel's counters stay bit-identical)
+                ws = w_word & _FIELD_MASK
+                wd = (w_word >> _DST_SHIFT) & _FIELD_MASK
+                t_w = tmap[ws, wd]
+                ok_w = w_consume & (t_w >= 0)
+                rowc = (jnp.arange(C) // n_ch) * T + jnp.clip(t_w, 0, T - 1)
+                cons_t = cons_t.at[rowc].add(ok_w.astype(jnp.int32))
+                consm_t = consm_t.at[rowc].add(
+                    (ok_w & measure).astype(jnp.int32))
+                t_i = tof[fid]
+                rowi = (jnp.arange(N) // n) * T + jnp.clip(t_i, 0, T - 1)
+                inj_t = inj_t.at[rowi].add(
+                    (inj & (t_i >= 0)).astype(jnp.int32))
+
+            if adaptive:
+                popped = w_pop[qrows // n_vc] & (win_q[qrows // n_vc] == qrows)
+                stall = jnp.where(nonempty & ~popped, stall + 1, 0)
+                escaped = escaped + (w_push & (tgt % n_vc == 0)
+                                     & (win_q % n_vc != 0)).reshape(
+                    R, n_ch).sum(axis=1)
+
+        with jax.named_scope("watchdog"):
+            pop_lane = w_pop.reshape(R, n_ch).sum(axis=1)
+            hops = hops + pop_lane          # packet-hops: pops of any queue
+            progress = (pop_lane > 0) | (inj_lane > 0)
+            wstall = jnp.where((injected - consumed > 0) & ~progress,
+                               wstall + 1, 0)
+            stalled_at = jnp.where((wstall >= watchdog) & (stalled_at < 0),
+                                   i, stalled_at)
         return (i + 1, q, head, size, rr, busy, key, stall, wstall,
                 stalled_at,
                 (offered, accepted, tagged, consumed_meas, consumed,
-                 injected, escaped, inj_t, cons_t, consm_t))
+                 injected, escaped, hops, inj_t, cons_t, consm_t))
 
-    stats0 = (jnp.zeros((R,), jnp.int32),) * 7 \
+    stats0 = (jnp.zeros((R,), jnp.int32),) * 8 \
         + (jnp.zeros((R * T,), jnp.int32),) * 3
     stall0 = jnp.zeros((NQ if adaptive else 1,), jnp.int32)
     carry = (jnp.int32(0), q, head, size, rr, busy, key, stall0,
@@ -804,7 +825,7 @@ def _sweep_dense(ch_dst, pv, fdst, src_ptr, deg, fprob, falias,
     q, head, size = carry[1], carry[2], carry[3]
     stalled_at = carry[9]
     (offered, accepted, tagged, consumed_meas, consumed, injected,
-     escaped, inj_t, cons_t, consm_t) = carry[-1]
+     escaped, hops, inj_t, cons_t, consm_t) = carry[-1]
     if T:
         # per-tenant end-of-run occupancy from the final ring buffers:
         # slot j of queue r holds a live word iff (j - head) % slots
@@ -819,8 +840,17 @@ def _sweep_dense(ch_dst, pv, fdst, src_ptr, deg, fprob, falias,
     else:
         infl_t = jnp.zeros((0,), jnp.int32)
     return (offered, accepted, tagged, consumed_meas, consumed, injected,
-            escaped, size.reshape(R, -1).sum(axis=1), stalled_at,
+            escaped, hops, size.reshape(R, -1).sum(axis=1), stalled_at,
             inj_t, cons_t, consm_t, infl_t, carry[0])
+
+
+# The device programs the sweep kernels compile to, by the names that the
+# profiler's trace gives their modules (followed there by a fingerprint in
+# parentheses). Inside them the phases of the cycle body carry named
+# scopes (route, arbitrate, crossbar, push, inject, scatter, counters,
+# watchdog), which name each device operation's ``tf_op`` in the trace.
+KERNEL_PROGRAMS = tuple(f"jit_{f.__name__}"
+                        for f in (_sweep_csr, _sweep_dense))
 
 
 def _compiled_flows(traffic, tables: SimTables) -> CompiledFlowTraffic:
@@ -885,7 +915,9 @@ def adaptive_spec(topo: Topology,
 class _SweepCall:
     """One sweep's kernel execution: ``fn(*args, **static)`` is exactly
     what :func:`sweep` runs (``fn`` is None when the traffic routes no
-    flow), plus what it needs to decode the result."""
+    flow), plus what it needs to decode the result. ``args`` are host
+    arrays, but for the PRNG key, which JAX makes on the device;
+    :func:`sweep` puts them on the device in one place."""
     fn: Optional[Callable]
     args: tuple
     static: dict
@@ -1005,8 +1037,7 @@ def _sweep_call(tables: SimTables, rates: Sequence[float], traffic, *,
                 "lost pairs and remaps flow ids)")
         dstN = np.asarray(t.dst, np.int32)   # flow -> destination node
         route_bytes = pvf.nbytes + hptr.nbytes + lenm1.nbytes + dstN.nbytes
-        args = (jnp.asarray(tables.ch_dst), jnp.asarray(pvf),
-                jnp.asarray(hptr), jnp.asarray(lenm1), jnp.asarray(dstN))
+        args = (tables.ch_dst, pvf, hptr, lenm1, dstN)
         fn = _sweep_csr
     elif kernel == "dense":
         if tables.n > _FIELD_MASK:
@@ -1018,20 +1049,15 @@ def _sweep_call(tables: SimTables, rates: Sequence[float], traffic, *,
                       + tables.vcs.astype(np.int32)).astype(np.int32)
         fdst = np.asarray(tables.csr().dst, np.int32)
         route_bytes = pv.nbytes + fdst.nbytes
-        args = (jnp.asarray(tables.ch_dst), jnp.asarray(pv),
-                jnp.asarray(fdst))
+        args = (tables.ch_dst, pv, fdst)
         fn = _sweep_dense
     else:
         raise ValueError(f"unknown kernel {kernel!r}")
     args = args + (
-        jnp.asarray(ct.src_indptr[:-1]), jnp.asarray(ct.deg),
-        jnp.asarray(ct.prob), jnp.asarray(ct.alias),
-        jnp.asarray(ct.src_rate), jnp.asarray(rates),
-        jax.random.PRNGKey(seed), jnp.asarray(outch_np),
-        jnp.asarray(minmask_np), jnp.asarray(esc_np), jnp.asarray(alive_np),
-        jnp.int32(t_fault), jnp.float32(g_on), jnp.float32(g_off),
-        jnp.asarray(np.asarray(phase_np, np.int32)), jnp.asarray(tof_np),
-        jnp.asarray(tmap_np), jnp.asarray(phase_of_np))
+        ct.src_indptr[:-1], ct.deg, ct.prob, ct.alias, ct.src_rate, rates,
+        jax.random.PRNGKey(seed), outch_np, minmask_np, esc_np, alive_np,
+        np.int32(t_fault), np.float32(g_on), np.float32(g_off),
+        np.asarray(phase_np, np.int32), tof_np, tmap_np, phase_of_np)
     static = dict(R=R, n=tables.n, n_ch=tables.n_ch, n_vc=tables.n_vc,
                   slots=slots, cycles=cycles, warmup=warmup, flits=flits,
                   adaptive=adaptive_on, faulted=faulted, bursty=bursty,
@@ -1086,42 +1112,69 @@ def sweep(tables: SimTables, rates: Sequence[float],
     entry to every rate dict -- per-tenant injected / consumed /
     in-flight packet counts (exact conservation: injected == consumed +
     in-flight) and delivered throughput per tenant node.
+
+    Every rate dict counts ``hops``, the packet-hops of the whole run:
+    pops from a channel queue, each a packet moving on to its next
+    channel or being consumed. The call records the program spans
+    ``netsim.sweep`` and its stages ``.assemble`` (validation and host
+    arrays), ``.upload`` (the arguments put on the device, with the
+    counter ``netsim.sweep.upload_bytes``), ``.run`` (dispatch until
+    the outputs are ready) and ``.decode`` (the rate dicts, with the
+    counter ``netsim.sweep.hops``).
     """
-    call = _sweep_call(tables, rates, traffic, cycles=cycles,
-                       warmup=warmup, slots=slots, seed=seed, flits=flits,
-                       kernel=kernel, adaptive=adaptive, fault=fault,
-                       patience=patience, watchdog=watchdog)
-    if stats is not None:
-        stats["kernel"] = kernel
-        stats["array_bytes"] = max(stats.get("array_bytes", 0),
-                                   call.array_bytes)
-    if call.fn is None:
+    with obs.span("netsim.sweep"):
+        with obs.span("netsim.sweep.assemble"):
+            call = _sweep_call(tables, rates, traffic, cycles=cycles,
+                               warmup=warmup, slots=slots, seed=seed,
+                               flits=flits, kernel=kernel,
+                               adaptive=adaptive, fault=fault,
+                               patience=patience, watchdog=watchdog)
         if stats is not None:
-            stats["cycles_run"] = cycles
-        return [{"rate": float(r), "offered": 0.0, "accepted": 0.0,
-                 "delivered": 0.0, "delivered_tagged": 0.0,
-                 "consumed_total": 0, "injected_total": 0, "in_flight": 0,
-                 "escaped": 0, "stalled_at": -1}
-                for r in call.rates]
-    out = call.fn(*call.args, **call.static)
+            stats["kernel"] = kernel
+            stats["array_bytes"] = max(stats.get("array_bytes", 0),
+                                       call.array_bytes)
+        if call.fn is None:
+            if stats is not None:
+                stats["cycles_run"] = cycles
+            return [{"rate": float(r), "offered": 0.0, "accepted": 0.0,
+                     "delivered": 0.0, "delivered_tagged": 0.0,
+                     "consumed_total": 0, "injected_total": 0,
+                     "in_flight": 0, "escaped": 0, "stalled_at": -1,
+                     "hops": 0}
+                    for r in call.rates]
+        with obs.span("netsim.sweep.upload"):
+            args = jax.block_until_ready(jax.device_put(call.args))
+            obs.count("netsim.sweep.upload_bytes",
+                      sum(a.nbytes for a in call.args))
+        with obs.span("netsim.sweep.run"):
+            out = jax.block_until_ready(call.fn(*args, **call.static))
+        with obs.span("netsim.sweep.decode"):
+            lanes = _decode(call, out, tables.n, cycles - warmup, stats)
+            obs.count("netsim.sweep.hops", sum(r["hops"] for r in lanes))
+        return lanes
+
+
+def _decode(call: _SweepCall, out: tuple, n: int, meas: int,
+            stats: Optional[dict]) -> List[Dict]:
+    """The kernel's outputs as one dict per rate; ``meas`` is the
+    number of measured cycles and ``n`` the node count."""
     rates, tenants = call.rates, call.tenants
     T = call.static["T"]
-    (off, acc, tagd, consm, cons, injd, escd, infl, stalled,
+    (off, acc, tagd, consm, cons, injd, escd, hops, infl, stalled,
      inj_t, cons_t, consm_t, infl_t) = (np.asarray(a) for a in out[:-1])
     cycles_run = int(out[-1])
     if stats is not None:
         stats["cycles_run"] = cycles_run
-    meas = cycles - warmup
     trace = []
     for i, rate in enumerate(rates):
         trace.append({
             "rate": float(rate),
-            "offered": float(off[i]) / meas / tables.n,
-            "accepted": float(acc[i]) / meas / tables.n,
+            "offered": float(off[i]) / meas / n,
+            "accepted": float(acc[i]) / meas / n,
             # steady-state throughput: window consumption rate
-            "delivered": float(consm[i]) / meas / tables.n,
+            "delivered": float(consm[i]) / meas / n,
             # conservation-safe: only packets injected inside the window
-            "delivered_tagged": float(tagd[i]) / meas / tables.n,
+            "delivered_tagged": float(tagd[i]) / meas / n,
             "consumed_total": int(cons[i]),
             "injected_total": int(injd[i]),
             "in_flight": int(infl[i]),
@@ -1129,6 +1182,9 @@ def sweep(tables: SimTables, rates: Sequence[float],
             # the lane's watchdog fired (-1 = never stalled)
             "escaped": int(escd[i]),
             "stalled_at": int(stalled[i]),
+            # packet-hops: pops from any channel queue, each a packet
+            # moving on to its next channel or being consumed
+            "hops": int(hops[i]),
         })
         if T:
             # per-tenant accounting (exact conservation:

@@ -7,7 +7,7 @@ four benchmarks and the examples, each with its own kwarg tunnel.
 :func:`route_pod` runs the same stages off one :class:`PipelineConfig`
 and returns a :class:`RoutedPod` carrying every intermediate the call
 sites used to re-derive (allowed turns, routing result, VC counts,
-simulator tables, per-stage wall-clock). This module adds no routing
+simulator tables, per-stage seconds). This module adds no routing
 semantics of its own -- the staged functions stay the extension
 surface -- and a migrated call site produces bit-identical tables for
 the same config and seed (tests/test_pipeline.py proves it against the
@@ -29,11 +29,11 @@ Three VC modes cover every internal consumer:
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Dict, Optional, Union
 
 import numpy as np
 
+from repro.core import obs
 from repro.core.routing import (ATResult, RoutingResult, allowed_turns,
                                 select_paths)
 from repro.core.topology import Topology
@@ -93,6 +93,8 @@ class RoutedPod:
     vc_counts: Optional[np.ndarray] = None  # (n_vc,) (vc="inplace")
     vc_stats: Optional[dict] = None
     deadlock_free: Optional[bool] = None  # set when cfg.verify
+    # at_s / select_s / vc_s: seconds of the spans routing.allowed_turns,
+    # routing.select and pipeline.vc (repro.core.obs)
     timings: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     @property
@@ -136,48 +138,50 @@ def route_pod(topo: Topology, cfg: Optional[PipelineConfig] = None, *,
     """
     cfg = cfg or PipelineConfig()
     timings: Dict[str, float] = {}
-    if at is None:
-        t0 = time.time()
-        at = allowed_turns(topo, n_vc=cfg.n_vc, priority=cfg.priority,
-                           robust=cfg.robust, seed=cfg.seed,
-                           chosen_loads=chosen_loads,
-                           at_engine=cfg.at_engine)
-        timings["at_s"] = time.time() - t0
-    kw = dict(K=cfg.K, seed=cfg.seed, engine=cfg.engine,
-              dead_channels=dead_channels,
-              local_search_rounds=cfg.local_search_rounds,
-              block=cfg.block, shard_sources=cfg.shard_sources,
-              rounds=cfg.rounds, k_min=cfg.k_min,
-              refine_cap=cfg.refine_cap, uniq_dp=cfg.uniq_dp,
-              pair_weight=pair_weight,
-              dist_out=dist_out, best_out=best_out)
-    kw.update(select_kw or {})
-    t0 = time.time()
-    routed = select_paths(at, **kw)
-    timings["select_s"] = time.time() - t0
+    with obs.span("pipeline.route_pod"):
+        if at is None:
+            with obs.span("routing.allowed_turns") as s:
+                at = allowed_turns(topo, n_vc=cfg.n_vc,
+                                   priority=cfg.priority,
+                                   robust=cfg.robust, seed=cfg.seed,
+                                   chosen_loads=chosen_loads,
+                                   at_engine=cfg.at_engine)
+            timings["at_s"] = s.seconds
+        kw = dict(K=cfg.K, seed=cfg.seed, engine=cfg.engine,
+                  dead_channels=dead_channels,
+                  local_search_rounds=cfg.local_search_rounds,
+                  block=cfg.block, shard_sources=cfg.shard_sources,
+                  rounds=cfg.rounds, k_min=cfg.k_min,
+                  refine_cap=cfg.refine_cap, uniq_dp=cfg.uniq_dp,
+                  pair_weight=pair_weight,
+                  dist_out=dist_out, best_out=best_out)
+        kw.update(select_kw or {})
+        with obs.span("routing.select") as s:
+            routed = select_paths(at, **kw)
+        timings["select_s"] = s.seconds
 
-    tables = None
-    vc_counts = None
-    vc_stats: dict = {}
-    t0 = time.time()
-    if cfg.vc == "tables":
-        from repro.core.netsim import at_tables
-        tables = at_tables(topo, at, routed, balance=cfg.balance,
-                           stats=vc_stats,
-                           reserve_escape=cfg.reserve_escape)
-    elif cfg.vc == "inplace":
-        from repro.core.vcalloc import allocate_vcs
-        vc_counts = allocate_vcs(
-            at, routed.table,
-            balance=True if cfg.balance is None else cfg.balance,
-            stats=vc_stats, reserve_escape=cfg.reserve_escape)
-    timings["vc_s"] = time.time() - t0
+        tables = None
+        vc_counts = None
+        vc_stats: dict = {}
+        with obs.span("pipeline.vc") as s:
+            if cfg.vc == "tables":
+                from repro.core.netsim import at_tables
+                tables = at_tables(topo, at, routed, balance=cfg.balance,
+                                   stats=vc_stats,
+                                   reserve_escape=cfg.reserve_escape)
+            elif cfg.vc == "inplace":
+                from repro.core.vcalloc import allocate_vcs
+                vc_counts = allocate_vcs(
+                    at, routed.table,
+                    balance=True if cfg.balance is None else cfg.balance,
+                    stats=vc_stats, reserve_escape=cfg.reserve_escape)
+        timings["vc_s"] = s.seconds
 
-    deadlock_free = None
-    if cfg.verify:
-        from repro.core.vcalloc import verify_deadlock_free
-        tbl = tables.table if tables is not None else routed.table
-        deadlock_free = bool(verify_deadlock_free(at, tbl))
+        deadlock_free = None
+        if cfg.verify:
+            from repro.core.vcalloc import verify_deadlock_free
+            tbl = tables.table if tables is not None else routed.table
+            deadlock_free = bool(verify_deadlock_free(at, tbl))
     return RoutedPod(topo, cfg, at, routed, tables=tables,
                      vc_counts=vc_counts, vc_stats=vc_stats,
                      deadlock_free=deadlock_free, timings=timings)

@@ -71,13 +71,13 @@ few percent of a cold rebuild.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import List, Optional, Tuple
 
 import numpy as np
 import scipy.sparse as sp
 from scipy.sparse import csgraph
 
+from repro.core import obs
 from repro.core.pathtable import CSRPathTable
 from repro.core.routing import (ATResult, Channels, RoutingResult,
                                 _BatchedDAG, _dead_channel_array,
@@ -208,11 +208,15 @@ class ServingState:
 @dataclasses.dataclass
 class RepairResult:
     """Outcome of one :func:`repair_fault` / :func:`restore_channels`
-    call. ``stats`` carries the per-stage wall-clock (``prune_s``,
+    call. ``stats`` carries the per-stage seconds (``prune_s``,
     ``walk_s``, ``bfs_s``, ``readmit_s``, ``greedy_s``, ``refine_s``,
     ``vc_s``, ``verify_s``, ``total_s``) plus pool/residual sizes; it
     is JSON-serialised by the benchmark lanes, so everything in it
-    stays scalar. The re-routed flow-id pool rides separately on
+    stays scalar. Each stage is a program span (:mod:`repro.core.obs`),
+    ``repair.repair_fault`` or ``repair.heal`` (``total_s``) with the
+    children ``.prune``, ``.readmit``, ``.walk``, ``.bfs``, ``.greedy``,
+    ``.refine``, ``.vc``, ``.verify`` and ``.fallback``; a key that
+    sums several passes of one stage sums its spans. The re-routed flow-id pool rides separately on
     ``pool_flows`` (the complement is the untouched set whose paths
     must be bit-identical to the pre-event table)."""
     state: ServingState
@@ -556,201 +560,199 @@ def repair_fault(state: ServingState, dead_channels,
     if on_disconnect not in ("degrade", "recompute"):
         raise ValueError(f"on_disconnect must be 'degrade' or "
                          f"'recompute', got {on_disconnect!r}")
-    t_all = time.time()
-    stats: dict = {}
-    at = state.at
-    ch = at.channels
-    n, n_vc = ch.n_nodes, at.n_vc
-    SEN = ch.n
-    K = state.K
-    dc = _validated_dead(dead_channels, SEN)
-    new = np.setdiff1d(dc, state.dead)
-    stats["already_dead"] = int(len(dc) - len(new))
-    dead_all = np.union1d(state.dead, dc)
-    dead_mask = np.zeros(SEN, bool)
-    dead_mask[dead_all] = True
-    new_mask = np.zeros(SEN, bool)
-    new_mask[new] = True
-    dead_state = (dead_all[:, None] * n_vc
-                  + np.arange(n_vc)).ravel() if len(dead_all) else \
-        np.zeros(0, np.int64)
+    with obs.span("repair.repair_fault") as root:
+        stats: dict = {}
+        at = state.at
+        ch = at.channels
+        n, n_vc = ch.n_nodes, at.n_vc
+        SEN = ch.n
+        K = state.K
+        dc = _validated_dead(dead_channels, SEN)
+        new = np.setdiff1d(dc, state.dead)
+        stats["already_dead"] = int(len(dc) - len(new))
+        dead_all = np.union1d(state.dead, dc)
+        dead_mask = np.zeros(SEN, bool)
+        dead_mask[dead_all] = True
+        new_mask = np.zeros(SEN, bool)
+        new_mask[new] = True
+        dead_state = (dead_all[:, None] * n_vc
+                      + np.arange(n_vc)).ravel() if len(dead_all) else \
+            np.zeros(0, np.int64)
 
-    # ---- stage A: delta allowed-turns admission (prune) -------------------
-    t0 = time.time()
-    at2 = _pruned_at(at, dead_mask)
-    stats["prune_s"] = round(time.time() - t0, 3)
-    readmitted = 0
-    if readmit == "always":
-        t0 = time.time()
-        readmitted = _readmit(at2)
-        stats["readmit_s_upfront"] = round(time.time() - t0, 3)
-
-    # ---- stage B: selective re-selection ----------------------------------
-    table = state.table
-    F = table.n_flows
-    flen_all = table.flow_len.astype(np.int64)
-    # flows whose path crosses a newly-dead channel: searchsorted the
-    # dead hop positions back to flow ids (cheaper than materialising
-    # the tens-of-millions-entry hop->flow map at 12^3+)
-    dead_hops = np.nonzero(new_mask[table.chan])[0]
-    pool = np.unique(np.searchsorted(table.hop_indptr, dead_hops,
-                                     side="right") - 1)
-    stats["pool"] = len(pool)
-    loads = state.loads.copy()
-    counts = state.vc_counts.copy()
-    dist_store, best_store = state.dist, state.best
-    store_copied = False
-    fallback = False
-    unreachable = 0
-    t_walk = t_bfs = t_readmit = t_greedy = t_refine = t_vc = 0.0
-    rng = np.random.default_rng(state.seed)
-
-    if len(pool):
-        src_all = table.flow_src.astype(np.int64)
-        psrc, pdst = src_all[pool], table.dst[pool].astype(np.int64)
-        pool_hop_idx = _pool_hop_ranges(table, pool)
-        loads[:SEN] -= np.bincount(table.chan[pool_hop_idx],
-                                   minlength=SEN)
-        loads[SEN] = 0
-        counts = counts - np.bincount(
-            table.vc[pool_hop_idx].astype(np.int64), minlength=n_vc)
-
-        # stale-distance walk: completed chains are sound, dead walkers
-        # form the residual that gets an exact BFS below
-        t0 = time.time()
-        cand, vcs, kv, plens = _walk_pool_chunked(
-            at2, dist_store, best_store, dead_state, psrc, pdst, K)
-        t_walk += time.time() - t0
-        residual = np.nonzero(~kv.any(axis=1))[0]
-        stats["residual"] = len(residual)
-        for attempt in range(2):
-            if not len(residual):
-                break
-            if attempt == 1:
-                # the exact BFS still found nothing: only new turns can
-                # help -- resume admission, then re-measure
-                if readmit == "never" or readmitted:
-                    break
-                t0 = time.time()
+        # ---- stage A: delta allowed-turns admission (prune) -----------------
+        with obs.span("repair.repair_fault.prune") as stage:
+            at2 = _pruned_at(at, dead_mask)
+        stats["prune_s"] = stage.seconds
+        readmitted = 0
+        if readmit == "always":
+            with obs.span("repair.repair_fault.readmit") as stage:
                 readmitted = _readmit(at2)
-                t_readmit += time.time() - t0
-                if not readmitted:
-                    break
-            t0 = time.time()
-            rsrcs = np.unique(psrc[residual])
-            if not store_copied:
-                dist_store = dist_store.copy()
-                best_store = best_store.copy()
-                store_copied = True
-            d = _exact_bfs(at2, rsrcs, dead_all, chunk=bfs_chunk)
-            b = node_distances(at2, rsrcs, dist=d)
-            dist_store[rsrcs] = d.astype(np.int8)
-            best_store[rsrcs] = b.astype(np.int16)
-            t_bfs += time.time() - t0
-            t0 = time.time()
-            rc, rv, rkv, rlens = _walk_pool_chunked(
-                at2, dist_store, best_store, dead_state,
-                psrc[residual], pdst[residual], K)
-            t_walk += time.time() - t0
-            Lp = max(cand.shape[2], rc.shape[2])
-            if Lp > cand.shape[2]:
-                grown = np.full((len(pool), K, Lp), SEN, np.int64)
-                grown[:, :, :cand.shape[2]] = cand
-                cand = grown
-                gv = np.zeros((len(pool), K, Lp), np.int8)
-                gv[:, :, :vcs.shape[2]] = vcs
-                vcs = gv
-            cand[residual, :, :rc.shape[2]] = rc
-            cand[residual, :, rc.shape[2]:] = SEN
-            vcs[residual, :, :rv.shape[2]] = rv
-            vcs[residual, :, rv.shape[2]:] = 0
-            kv[residual] = rkv
-            plens[residual] = rlens
-            residual = residual[~rkv.any(axis=1)]
-        unreachable = int(len(residual))
+            stats["readmit_s_upfront"] = stage.seconds
 
-        if unreachable and readmit != "never" \
-                and on_disconnect == "recompute":
-            # legacy policy: the pruned AT (even after re-admission)
-            # cannot route some pooled flow along stored/exact fields --
-            # give up on the incremental path, re-select everything
-            fallback = True
-        else:
-            routable = np.nonzero(kv.any(axis=1))[0]
-            # same min-max tie-break base as the selection engines:
-            # strictly larger than any sum-of-loads along one path
-            BIG = np.int64(F) * max(int(flen_all.max()), 1) + 1
-            t0 = time.time()
-            pchosen = _greedy_assign(loads, cand, kv, routable, rng,
-                                     SEN, BIG, block)
-            t_greedy += time.time() - t0
-            # the sharded engine's refinement primitive over the pool
-            t0 = time.time()
-            if local_search_rounds > 0 and len(routable):
-                lm_before = int(loads[:SEN].max())
-                loads, sub_chosen = _refine_candidates(
-                    loads, cand[routable], kv[routable],
-                    pchosen[routable].copy(), rng, SEN, BIG,
-                    local_search_rounds, refine_block, lm_before)
-                pchosen[routable] = sub_chosen
-            t_refine += time.time() - t0
-            table = _rebuild_table(table, pool, pool_hop_idx, plens,
-                                   kv, cand, vcs, pchosen, SEN)
-    else:
-        stats["residual"] = 0
-        table = state.table.copy()
-
-    if fallback:
-        # full re-selection + allocation on the pruned AT -- same
-        # channel-id space, full recompute semantics
-        t0 = time.time()
-        routed = select_paths(at2, K=K, seed=state.seed,
-                              engine="sharded", dead_channels=dead_all)
-        table = routed.table
-        loads = np.zeros(SEN + 1, np.int64)
-        loads[:SEN] = routed.loads.astype(np.int64)
-        counts = allocate_vcs(at2, table)
-        unreachable = routed.unreachable
-        stats["fallback_s"] = round(time.time() - t0, 3)
-    elif len(pool):
-        # ---- stage C: streamed VC re-repair over the pool -----------------
-        t0 = time.time()
-        counts = reallocate_vcs(at2, table, pool, counts)
-        t_vc += time.time() - t0
-
-    t0 = time.time()
-    if verify == "full" or fallback:
-        deadlock_free = verify_deadlock_free(at2, table)
-    elif len(pool):
-        deadlock_free = verify_flows_deadlock_free(at2, table, pool)
-    else:
-        deadlock_free = True
-    stats["verify_s"] = round(time.time() - t0, 3)
-
-    stats.update({"walk_s": round(t_walk, 3), "bfs_s": round(t_bfs, 3),
-                  "readmit_s": round(t_readmit, 3),
-                  "greedy_s": round(t_greedy, 3),
-                  "refine_s": round(t_refine, 3),
-                  "vc_s": round(t_vc, 3)})
-    if not store_copied and not fallback:
+        # ---- stage B: selective re-selection --------------------------------
+        table = state.table
+        F = table.n_flows
+        flen_all = table.flow_len.astype(np.int64)
+        # flows whose path crosses a newly-dead channel: searchsorted the
+        # dead hop positions back to flow ids (cheaper than materialising
+        # the tens-of-millions-entry hop->flow map at 12^3+)
+        dead_hops = np.nonzero(new_mask[table.chan])[0]
+        pool = np.unique(np.searchsorted(table.hop_indptr, dead_hops,
+                                         side="right") - 1)
+        stats["pool"] = len(pool)
+        loads = state.loads.copy()
+        counts = state.vc_counts.copy()
         dist_store, best_store = state.dist, state.best
-    if fallback:
-        # the fallback re-selection renumbers flows (unreachable pairs
-        # get no entry), so the flow-id bookkeeping resets
-        lost2 = np.zeros(0, np.int64)
-        touched2 = np.zeros(0, np.int64)
-    elif len(pool):
-        routable_m = kv.any(axis=1)
-        lost2 = np.union1d(state.lost, pool[~routable_m])
-        touched2 = np.union1d(state.touched, pool[routable_m])
-    else:
-        lost2, touched2 = state.lost, state.touched
-    stats["lost"] = int(len(lost2))
-    new_state = ServingState(state.topo, at2, table, loads, counts,
-                             dead_all, dist_store, best_store, K,
-                             state.seed, stats=state.stats, lost=lost2,
-                             touched=touched2, at0=state.at0)
-    stats["total_s"] = round(time.time() - t_all, 3)
+        store_copied = False
+        fallback = False
+        unreachable = 0
+        t_walk = t_bfs = t_readmit = t_greedy = t_refine = t_vc = 0.0
+        rng = np.random.default_rng(state.seed)
+
+        if len(pool):
+            src_all = table.flow_src.astype(np.int64)
+            psrc, pdst = src_all[pool], table.dst[pool].astype(np.int64)
+            pool_hop_idx = _pool_hop_ranges(table, pool)
+            loads[:SEN] -= np.bincount(table.chan[pool_hop_idx],
+                                       minlength=SEN)
+            loads[SEN] = 0
+            counts = counts - np.bincount(
+                table.vc[pool_hop_idx].astype(np.int64), minlength=n_vc)
+
+            # stale-distance walk: completed chains are sound, dead walkers
+            # form the residual that gets an exact BFS below
+            with obs.span("repair.repair_fault.walk") as stage:
+                cand, vcs, kv, plens = _walk_pool_chunked(
+                    at2, dist_store, best_store, dead_state, psrc, pdst, K)
+            t_walk += stage.seconds
+            residual = np.nonzero(~kv.any(axis=1))[0]
+            stats["residual"] = len(residual)
+            for attempt in range(2):
+                if not len(residual):
+                    break
+                if attempt == 1:
+                    # the exact BFS still found nothing: only new turns can
+                    # help -- resume admission, then re-measure
+                    if readmit == "never" or readmitted:
+                        break
+                    with obs.span("repair.repair_fault.readmit") as stage:
+                        readmitted = _readmit(at2)
+                    t_readmit += stage.seconds
+                    if not readmitted:
+                        break
+                with obs.span("repair.repair_fault.bfs") as stage:
+                    rsrcs = np.unique(psrc[residual])
+                    if not store_copied:
+                        dist_store = dist_store.copy()
+                        best_store = best_store.copy()
+                        store_copied = True
+                    d = _exact_bfs(at2, rsrcs, dead_all, chunk=bfs_chunk)
+                    b = node_distances(at2, rsrcs, dist=d)
+                    dist_store[rsrcs] = d.astype(np.int8)
+                    best_store[rsrcs] = b.astype(np.int16)
+                t_bfs += stage.seconds
+                with obs.span("repair.repair_fault.walk") as stage:
+                    rc, rv, rkv, rlens = _walk_pool_chunked(
+                        at2, dist_store, best_store, dead_state,
+                        psrc[residual], pdst[residual], K)
+                t_walk += stage.seconds
+                Lp = max(cand.shape[2], rc.shape[2])
+                if Lp > cand.shape[2]:
+                    grown = np.full((len(pool), K, Lp), SEN, np.int64)
+                    grown[:, :, :cand.shape[2]] = cand
+                    cand = grown
+                    gv = np.zeros((len(pool), K, Lp), np.int8)
+                    gv[:, :, :vcs.shape[2]] = vcs
+                    vcs = gv
+                cand[residual, :, :rc.shape[2]] = rc
+                cand[residual, :, rc.shape[2]:] = SEN
+                vcs[residual, :, :rv.shape[2]] = rv
+                vcs[residual, :, rv.shape[2]:] = 0
+                kv[residual] = rkv
+                plens[residual] = rlens
+                residual = residual[~rkv.any(axis=1)]
+            unreachable = int(len(residual))
+
+            if unreachable and readmit != "never" \
+                    and on_disconnect == "recompute":
+                # legacy policy: the pruned AT (even after re-admission)
+                # cannot route some pooled flow along stored/exact fields --
+                # give up on the incremental path, re-select everything
+                fallback = True
+            else:
+                routable = np.nonzero(kv.any(axis=1))[0]
+                # same min-max tie-break base as the selection engines:
+                # strictly larger than any sum-of-loads along one path
+                BIG = np.int64(F) * max(int(flen_all.max()), 1) + 1
+                with obs.span("repair.repair_fault.greedy") as stage:
+                    pchosen = _greedy_assign(loads, cand, kv, routable, rng,
+                                             SEN, BIG, block)
+                t_greedy += stage.seconds
+                # the sharded engine's refinement primitive over the pool
+                with obs.span("repair.repair_fault.refine") as stage:
+                    if local_search_rounds > 0 and len(routable):
+                        lm_before = int(loads[:SEN].max())
+                        loads, sub_chosen = _refine_candidates(
+                            loads, cand[routable], kv[routable],
+                            pchosen[routable].copy(), rng, SEN, BIG,
+                            local_search_rounds, refine_block, lm_before)
+                        pchosen[routable] = sub_chosen
+                t_refine += stage.seconds
+                table = _rebuild_table(table, pool, pool_hop_idx, plens,
+                                       kv, cand, vcs, pchosen, SEN)
+        else:
+            stats["residual"] = 0
+            table = state.table.copy()
+
+        if fallback:
+            # full re-selection + allocation on the pruned AT -- same
+            # channel-id space, full recompute semantics
+            with obs.span("repair.repair_fault.fallback") as stage:
+                routed = select_paths(at2, K=K, seed=state.seed,
+                                      engine="sharded", dead_channels=dead_all)
+                table = routed.table
+                loads = np.zeros(SEN + 1, np.int64)
+                loads[:SEN] = routed.loads.astype(np.int64)
+                counts = allocate_vcs(at2, table)
+                unreachable = routed.unreachable
+            stats["fallback_s"] = stage.seconds
+        elif len(pool):
+            # ---- stage C: streamed VC re-repair over the pool ---------------
+            with obs.span("repair.repair_fault.vc") as stage:
+                counts = reallocate_vcs(at2, table, pool, counts)
+            t_vc += stage.seconds
+
+        with obs.span("repair.repair_fault.verify") as stage:
+            if verify == "full" or fallback:
+                deadlock_free = verify_deadlock_free(at2, table)
+            elif len(pool):
+                deadlock_free = verify_flows_deadlock_free(at2, table, pool)
+            else:
+                deadlock_free = True
+        stats["verify_s"] = stage.seconds
+
+        stats.update({"walk_s": t_walk, "bfs_s": t_bfs,
+                      "readmit_s": t_readmit, "greedy_s": t_greedy,
+                      "refine_s": t_refine, "vc_s": t_vc})
+        if not store_copied and not fallback:
+            dist_store, best_store = state.dist, state.best
+        if fallback:
+            # the fallback re-selection renumbers flows (unreachable pairs
+            # get no entry), so the flow-id bookkeeping resets
+            lost2 = np.zeros(0, np.int64)
+            touched2 = np.zeros(0, np.int64)
+        elif len(pool):
+            routable_m = kv.any(axis=1)
+            lost2 = np.union1d(state.lost, pool[~routable_m])
+            touched2 = np.union1d(state.touched, pool[routable_m])
+        else:
+            lost2, touched2 = state.lost, state.touched
+        stats["lost"] = int(len(lost2))
+        new_state = ServingState(state.topo, at2, table, loads, counts,
+                                 dead_all, dist_store, best_store, K,
+                                 state.seed, stats=state.stats, lost=lost2,
+                                 touched=touched2, at0=state.at0)
+    stats["total_s"] = root.seconds
     return RepairResult(new_state, flows_rerouted=len(pool),
                         l_max=float(loads[:SEN].max()),
                         unreachable=unreachable,
@@ -788,129 +790,128 @@ def restore_channels(state: ServingState, channels, rebalance: bool = True,
     Channels not currently dead are counted in ``stats["not_dead"]``
     and ignored; out-of-range ids raise ``ValueError``.
     """
-    t_all = time.time()
-    stats: dict = {}
-    at = state.at
-    ch = at.channels
-    n, n_vc = ch.n_nodes, at.n_vc
-    SEN = ch.n
-    K = state.K
-    dc = _validated_dead(channels, SEN)
-    revived = np.intersect1d(dc, state.dead)
-    stats["not_dead"] = int(len(dc) - len(revived))
-    dead_all = np.setdiff1d(state.dead, revived)
-    dead_mask = np.zeros(SEN, bool)
-    dead_mask[dead_all] = True
-    dead_state = (dead_all[:, None] * n_vc
-                  + np.arange(n_vc)).ravel() if len(dead_all) else \
-        np.zeros(0, np.int64)
-    full_heal = len(dead_all) == 0 and state.at0 is not None
+    with obs.span("repair.heal") as root:
+        stats: dict = {}
+        at = state.at
+        ch = at.channels
+        n, n_vc = ch.n_nodes, at.n_vc
+        SEN = ch.n
+        K = state.K
+        dc = _validated_dead(channels, SEN)
+        revived = np.intersect1d(dc, state.dead)
+        stats["not_dead"] = int(len(dc) - len(revived))
+        dead_all = np.setdiff1d(state.dead, revived)
+        dead_mask = np.zeros(SEN, bool)
+        dead_mask[dead_all] = True
+        dead_state = (dead_all[:, None] * n_vc
+                      + np.arange(n_vc)).ravel() if len(dead_all) else \
+            np.zeros(0, np.int64)
+        full_heal = len(dead_all) == 0 and state.at0 is not None
 
-    # ---- stage A: delta re-admission over the healed fabric ---------------
-    t0 = time.time()
-    readmitted = 0
-    if not len(revived):
-        at2 = at
-    elif full_heal:
-        at2 = state.at0
-        stats["exact_heal"] = True
-    else:
-        at2 = _revived_at(at, dead_mask)
-        readmitted = _readmit(at2)
-    stats["readmit_s"] = round(time.time() - t0, 3)
+        # ---- stage A: delta re-admission over the healed fabric -------------
+        with obs.span("repair.heal.readmit") as stage:
+            readmitted = 0
+            if not len(revived):
+                at2 = at
+            elif full_heal:
+                at2 = state.at0
+                stats["exact_heal"] = True
+            else:
+                at2 = _revived_at(at, dead_mask)
+                readmitted = _readmit(at2)
+        stats["readmit_s"] = stage.seconds
 
-    table = state.table
-    F = table.n_flows
-    flen_all = table.flow_len.astype(np.int64)
-    pool = state.lost
-    if rebalance or full_heal:
-        pool = np.union1d(pool, state.touched)
-    pool = pool.astype(np.int64)
-    stats["pool"] = len(pool)
-    stats["lost_before"] = int(len(state.lost))
-    loads = state.loads.copy()
-    counts = state.vc_counts.copy()
-    dist_store, best_store = state.dist, state.best
-    unreachable = 0
-    t_walk = t_bfs = t_greedy = t_refine = t_vc = 0.0
-    rng = np.random.default_rng(state.seed)
-    lost2, touched2 = state.lost, state.touched
+        table = state.table
+        F = table.n_flows
+        flen_all = table.flow_len.astype(np.int64)
+        pool = state.lost
+        if rebalance or full_heal:
+            pool = np.union1d(pool, state.touched)
+        pool = pool.astype(np.int64)
+        stats["pool"] = len(pool)
+        stats["lost_before"] = int(len(state.lost))
+        loads = state.loads.copy()
+        counts = state.vc_counts.copy()
+        dist_store, best_store = state.dist, state.best
+        unreachable = 0
+        t_walk = t_bfs = t_greedy = t_refine = t_vc = 0.0
+        rng = np.random.default_rng(state.seed)
+        lost2, touched2 = state.lost, state.touched
 
-    if len(pool):
-        src_all = table.flow_src.astype(np.int64)
-        psrc, pdst = src_all[pool], table.dst[pool].astype(np.int64)
-        pool_hop_idx = _pool_hop_ranges(table, pool)
-        loads[:SEN] -= np.bincount(table.chan[pool_hop_idx],
-                                   minlength=SEN)
-        loads[SEN] = 0
-        counts = counts - np.bincount(
-            table.vc[pool_hop_idx].astype(np.int64), minlength=n_vc)
+        if len(pool):
+            src_all = table.flow_src.astype(np.int64)
+            psrc, pdst = src_all[pool], table.dst[pool].astype(np.int64)
+            pool_hop_idx = _pool_hop_ranges(table, pool)
+            loads[:SEN] -= np.bincount(table.chan[pool_hop_idx],
+                                       minlength=SEN)
+            loads[SEN] = 0
+            counts = counts - np.bincount(
+                table.vc[pool_hop_idx].astype(np.int64), minlength=n_vc)
 
-        # exact distance refresh for every pooled source: the stored
-        # fields reflect the faulted fabric, and stale distances are
-        # only sound on a *subgraph* -- healing grows the graph, so the
-        # lost/touched walks need fresh exact BFS rows (copy-on-write)
-        t0 = time.time()
-        rsrcs = np.unique(psrc)
-        dist_store = dist_store.copy()
-        best_store = best_store.copy()
-        d = _exact_bfs(at2, rsrcs, dead_all, chunk=bfs_chunk)
-        b = node_distances(at2, rsrcs, dist=d)
-        dist_store[rsrcs] = d.astype(np.int8)
-        best_store[rsrcs] = b.astype(np.int16)
-        t_bfs += time.time() - t0
+            # exact distance refresh for every pooled source: the stored
+            # fields reflect the faulted fabric, and stale distances are
+            # only sound on a *subgraph* -- healing grows the graph, so the
+            # lost/touched walks need fresh exact BFS rows (copy-on-write)
+            with obs.span("repair.heal.bfs") as stage:
+                rsrcs = np.unique(psrc)
+                dist_store = dist_store.copy()
+                best_store = best_store.copy()
+                d = _exact_bfs(at2, rsrcs, dead_all, chunk=bfs_chunk)
+                b = node_distances(at2, rsrcs, dist=d)
+                dist_store[rsrcs] = d.astype(np.int8)
+                best_store[rsrcs] = b.astype(np.int16)
+            t_bfs += stage.seconds
 
-        t0 = time.time()
-        cand, vcs, kv, plens = _walk_pool_chunked(
-            at2, dist_store, best_store, dead_state, psrc, pdst, K)
-        t_walk += time.time() - t0
-        routable_m = kv.any(axis=1)
-        unreachable = int((~routable_m).sum())
-        routable = np.nonzero(routable_m)[0]
-        BIG = np.int64(F) * max(int(flen_all.max()),
-                                int(plens.max(initial=1)), 1) + 1
-        t0 = time.time()
-        pchosen = _greedy_assign(loads, cand, kv, routable, rng, SEN,
-                                 BIG, block)
-        t_greedy += time.time() - t0
-        t0 = time.time()
-        if local_search_rounds > 0 and len(routable):
-            lm_before = int(loads[:SEN].max())
-            loads, sub_chosen = _refine_candidates(
-                loads, cand[routable], kv[routable],
-                pchosen[routable].copy(), rng, SEN, BIG,
-                local_search_rounds, refine_block, lm_before)
-            pchosen[routable] = sub_chosen
-        t_refine += time.time() - t0
-        table = _rebuild_table(table, pool, pool_hop_idx, plens, kv,
-                               cand, vcs, pchosen, SEN)
-        # ---- stage C: streamed VC re-allocation over the pool -------------
-        t0 = time.time()
-        counts = reallocate_vcs(at2, table, pool, counts)
-        t_vc += time.time() - t0
-        lost2 = pool[~routable_m]
-        touched2 = np.union1d(state.touched, pool[routable_m])
-    else:
-        table = state.table.copy()
+            with obs.span("repair.heal.walk") as stage:
+                cand, vcs, kv, plens = _walk_pool_chunked(
+                    at2, dist_store, best_store, dead_state, psrc, pdst, K)
+            t_walk += stage.seconds
+            routable_m = kv.any(axis=1)
+            unreachable = int((~routable_m).sum())
+            routable = np.nonzero(routable_m)[0]
+            BIG = np.int64(F) * max(int(flen_all.max()),
+                                    int(plens.max(initial=1)), 1) + 1
+            with obs.span("repair.heal.greedy") as stage:
+                pchosen = _greedy_assign(loads, cand, kv, routable, rng, SEN,
+                                         BIG, block)
+            t_greedy += stage.seconds
+            with obs.span("repair.heal.refine") as stage:
+                if local_search_rounds > 0 and len(routable):
+                    lm_before = int(loads[:SEN].max())
+                    loads, sub_chosen = _refine_candidates(
+                        loads, cand[routable], kv[routable],
+                        pchosen[routable].copy(), rng, SEN, BIG,
+                        local_search_rounds, refine_block, lm_before)
+                    pchosen[routable] = sub_chosen
+            t_refine += stage.seconds
+            table = _rebuild_table(table, pool, pool_hop_idx, plens, kv,
+                                   cand, vcs, pchosen, SEN)
+            # ---- stage C: streamed VC re-allocation over the pool -----------
+            with obs.span("repair.heal.vc") as stage:
+                counts = reallocate_vcs(at2, table, pool, counts)
+            t_vc += stage.seconds
+            lost2 = pool[~routable_m]
+            touched2 = np.union1d(state.touched, pool[routable_m])
+        else:
+            table = state.table.copy()
 
-    t0 = time.time()
-    if verify == "full":
-        deadlock_free = verify_deadlock_free(at2, table)
-    elif len(pool):
-        deadlock_free = verify_flows_deadlock_free(at2, table, pool)
-    else:
-        deadlock_free = True
-    stats["verify_s"] = round(time.time() - t0, 3)
+        with obs.span("repair.heal.verify") as stage:
+            if verify == "full":
+                deadlock_free = verify_deadlock_free(at2, table)
+            elif len(pool):
+                deadlock_free = verify_flows_deadlock_free(at2, table, pool)
+            else:
+                deadlock_free = True
+        stats["verify_s"] = stage.seconds
 
-    stats.update({"walk_s": round(t_walk, 3), "bfs_s": round(t_bfs, 3),
-                  "greedy_s": round(t_greedy, 3),
-                  "refine_s": round(t_refine, 3),
-                  "vc_s": round(t_vc, 3), "lost": int(len(lost2))})
-    new_state = ServingState(state.topo, at2, table, loads, counts,
-                             dead_all, dist_store, best_store, K,
-                             state.seed, stats=state.stats, lost=lost2,
-                             touched=touched2, at0=state.at0)
-    stats["total_s"] = round(time.time() - t_all, 3)
+        stats.update({"walk_s": t_walk, "bfs_s": t_bfs,
+                      "greedy_s": t_greedy, "refine_s": t_refine,
+                      "vc_s": t_vc, "lost": int(len(lost2))})
+        new_state = ServingState(state.topo, at2, table, loads, counts,
+                                 dead_all, dist_store, best_store, K,
+                                 state.seed, stats=state.stats, lost=lost2,
+                                 touched=touched2, at0=state.at0)
+    stats["total_s"] = root.seconds
     return RepairResult(new_state, flows_rerouted=len(pool),
                         l_max=float(loads[:SEN].max()),
                         unreachable=unreachable,

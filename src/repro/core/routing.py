@@ -77,6 +77,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core import obs
 from repro.core.pathtable import MAXHOP, CSRPathTable, PathTable
 from repro.core.topology import Topology
 
@@ -2154,6 +2155,12 @@ def _select_sharded(at: ATResult, K: int = 8, seed: int = 0,
     Emits a :class:`CSRPathTable` whose VC hops are the winning
     candidates' BFS state paths (valid by construction); the balanced
     re-allocation stays in :func:`repro.core.vcalloc.allocate_vcs`.
+
+    Stages are program spans (:mod:`repro.core.obs`):
+    ``routing.select.bfs`` (phase 0, with one ``.bfs.uniq`` per shard),
+    one ``.walk`` and one ``.greedy`` per pass of a round over a shard,
+    and ``.refine``; ``stats`` ``bfs_s``, ``uniq_s``, ``walk_s``,
+    ``greedy_s`` and ``refine_s`` are their summed seconds.
     """
     ch = at.channels
     sg = at.state_graph()
@@ -2176,67 +2183,67 @@ def _select_sharded(at: ATResult, K: int = 8, seed: int = 0,
     stats["uniq_dp"] = bool(uniq_dp)
 
     # ---- phase 0: per-shard BFS + CSR skeleton ---------------------------
-    t0 = time.time()
-    n_shards = (n + shard_sources - 1) // shard_sources
-    shard_dist: List[np.ndarray] = []
-    shard_best: List[np.ndarray] = []
-    shard_fb: List[np.ndarray] = []
-    shard_fd: List[np.ndarray] = []
-    shard_flen: List[np.ndarray] = []
-    shard_uniq: List[np.ndarray] = []
-    gid0 = np.zeros(n_shards + 1, np.int64)
-    src_flow_counts = np.zeros(n, np.int64)
-    unreachable = 0
-    uniq_flows = 0
-    t_nsp = 0.0
-    for si in range(n_shards):
-        s0 = si * shard_sources
-        srcs = np.arange(s0, min(s0 + shard_sources, n))
-        dist = state_bfs(at, srcs, dead_channels)
-        best = node_distances(at, srcs, dist=dist)
-        if dist_out is not None:
-            dist_out[srcs] = dist.astype(dist_out.dtype)
-        if best_out is not None:
-            best_out[srcs] = best.astype(best_out.dtype)
-        unreachable += int((best < 0).sum())
-        fb, fd = np.nonzero(best > 0)
-        flen = best[fb, fd].astype(np.int64)
-        if len(flen) and int(flen.max()) > MAXHOP:
-            raise ValueError(f"shortest path of {int(flen.max())} hops "
-                             f"exceeds MAXHOP={MAXHOP}")
-        if uniq_dp:
-            t1 = time.time()
-            uniq = _unique_channel_flows(sg, dist, best, n)[fb, fd]
-            t_nsp += time.time() - t1
-            uniq_flows += int(uniq.sum())
-        else:
-            uniq = np.zeros(len(fb), bool)
-        shard_dist.append(dist)
-        shard_best.append(best.astype(np.int16))
-        shard_fb.append(fb.astype(np.int64))
-        shard_fd.append(fd.astype(np.int64))
-        shard_flen.append(flen)
-        shard_uniq.append(uniq)
-        gid0[si + 1] = gid0[si] + len(fb)
-        src_flow_counts[srcs] = np.bincount(fb, minlength=len(srcs))
-    F = int(gid0[-1])
-    if refine_cap is None:
-        refine_cap = max(300_000, F // 24)
-    stats["refine_cap"] = int(refine_cap)
-    stats["uniq_flows"] = uniq_flows
-    stats["uniq_s"] = round(t_nsp, 3)
-    flen_all = (np.concatenate(shard_flen) if F else
-                np.zeros(0, np.int64)).astype(np.int64)
-    dst_all = (np.concatenate(shard_fd) if F else
-               np.zeros(0, np.int64)).astype(np.int32)
-    src_indptr = np.zeros(n + 1, np.int64)
-    np.cumsum(src_flow_counts, out=src_indptr[1:])
-    hop_indptr = np.zeros(F + 1, np.int64)
-    np.cumsum(flen_all, out=hop_indptr[1:])
-    chan_flat = np.zeros(int(hop_indptr[-1]), np.int32)
-    vc_flat = np.zeros(int(hop_indptr[-1]), np.int8)
-    chosen_k = np.zeros(F, np.int8)
-    stats["bfs_s"] = round(time.time() - t0, 3)
+    with obs.span("routing.select.bfs") as s_bfs:
+        n_shards = (n + shard_sources - 1) // shard_sources
+        shard_dist: List[np.ndarray] = []
+        shard_best: List[np.ndarray] = []
+        shard_fb: List[np.ndarray] = []
+        shard_fd: List[np.ndarray] = []
+        shard_flen: List[np.ndarray] = []
+        shard_uniq: List[np.ndarray] = []
+        gid0 = np.zeros(n_shards + 1, np.int64)
+        src_flow_counts = np.zeros(n, np.int64)
+        unreachable = 0
+        uniq_flows = 0
+        t_nsp = 0.0
+        for si in range(n_shards):
+            s0 = si * shard_sources
+            srcs = np.arange(s0, min(s0 + shard_sources, n))
+            dist = state_bfs(at, srcs, dead_channels)
+            best = node_distances(at, srcs, dist=dist)
+            if dist_out is not None:
+                dist_out[srcs] = dist.astype(dist_out.dtype)
+            if best_out is not None:
+                best_out[srcs] = best.astype(best_out.dtype)
+            unreachable += int((best < 0).sum())
+            fb, fd = np.nonzero(best > 0)
+            flen = best[fb, fd].astype(np.int64)
+            if len(flen) and int(flen.max()) > MAXHOP:
+                raise ValueError(f"shortest path of {int(flen.max())} hops "
+                                 f"exceeds MAXHOP={MAXHOP}")
+            if uniq_dp:
+                with obs.span("routing.select.bfs.uniq") as sp:
+                    uniq = _unique_channel_flows(sg, dist, best, n)[fb, fd]
+                t_nsp += sp.seconds
+                uniq_flows += int(uniq.sum())
+            else:
+                uniq = np.zeros(len(fb), bool)
+            shard_dist.append(dist)
+            shard_best.append(best.astype(np.int16))
+            shard_fb.append(fb.astype(np.int64))
+            shard_fd.append(fd.astype(np.int64))
+            shard_flen.append(flen)
+            shard_uniq.append(uniq)
+            gid0[si + 1] = gid0[si] + len(fb)
+            src_flow_counts[srcs] = np.bincount(fb, minlength=len(srcs))
+        F = int(gid0[-1])
+        if refine_cap is None:
+            refine_cap = max(300_000, F // 24)
+        stats["refine_cap"] = int(refine_cap)
+        stats["uniq_flows"] = uniq_flows
+        stats["uniq_s"] = t_nsp
+        flen_all = (np.concatenate(shard_flen) if F else
+                    np.zeros(0, np.int64)).astype(np.int64)
+        dst_all = (np.concatenate(shard_fd) if F else
+                   np.zeros(0, np.int64)).astype(np.int32)
+        src_indptr = np.zeros(n + 1, np.int64)
+        np.cumsum(src_flow_counts, out=src_indptr[1:])
+        hop_indptr = np.zeros(F + 1, np.int64)
+        np.cumsum(flen_all, out=hop_indptr[1:])
+        chan_flat = np.zeros(int(hop_indptr[-1]), np.int32)
+        vc_flat = np.zeros(int(hop_indptr[-1]), np.int8)
+        chosen_k = np.zeros(F, np.int8)
+    stats["bfs_s"] = s_bfs.seconds
     csr = CSRPathTable(n, SEN, n_vc, src_indptr, dst_all, hop_indptr,
                        chan_flat, vc_flat)
     if F == 0:
@@ -2260,145 +2267,145 @@ def _select_sharded(at: ATResult, K: int = 8, seed: int = 0,
             idx = perms[si][Fc * r // rounds:Fc * (r + 1) // rounds]
             if not len(idx):
                 continue
-            t1 = time.time()
-            s0 = si * shard_sources
-            srcs = np.arange(s0, min(s0 + shard_sources, n))
-            fl = flen[idx]
-            # adaptive budget: full K for flows touching the hot set
-            lm_run = int(loads[:SEN].max())
-            if lm_run > 1:
-                hotc = np.nonzero(
-                    loads[:SEN] >= max(2, int(hot_load_frac * lm_run)))[0]
-                hot_nodes = np.zeros(n, bool)
-                hot_nodes[ch.src[hotc]] = True
-                hot_nodes[ch.dst[hotc]] = True
-                hot_f = hot_nodes[s0 + fb[idx]] | hot_nodes[fd[idx]]
-            else:
-                hot_f = np.zeros(len(idx), bool)
-            uq = shard_uniq[si][idx]
-            kcap = np.where(hot_f, K, k_min)
-            kcap = np.minimum(kcap, np.where(fl == 1, 1,
-                                             np.where(fl == 2, 2, K)))
-            kcap = np.where(uq, 1, kcap)
-            k_full_flows += int((kcap >= K).sum())
-            chan_c, vc_c, kv = _walk_flows(sg, n, n_vc, SEN,
-                                           shard_dist[si], shard_best[si],
-                                           srcs, fb[idx], fd[idx], fl,
-                                           kcap, K, uniq=uq)
-            t_walk += time.time() - t1
-            t1 = time.time()
-            B, _, Lc = chan_c.shape
-            # fold this slice into the expected-load prior (uniform over
-            # each flow's valid slots), then damp the greedy with the
-            # scaled unprocessed remainder. Round 1 alone is an unbiased
-            # sample of every shard, so later rounds skip the scatter
-            # (it costs ~F*K*L adds) and reuse the round-1 estimate.
-            if r == 0 and damp > 0.0:
-                w = kv / kv.sum(axis=1)[:, None]
-                np.add.at(ehat, chan_c.ravel(),
-                          np.repeat(w.ravel(), Lc))
-                ehat[SEN] = 0.0
-                ehat_flows += B
-            scale = damp * (1.0 - done / F) * (F / max(ehat_flows, 1)) \
-                if ehat_flows else 0.0
-            chosen_local = np.zeros(B, np.int64)
-            for j in range(0, B, block):
-                bc = chan_c[j:j + block]
-                l = loads[bc].astype(np.float64)
-                if scale > 0.0:
-                    l += scale * ehat[bc]
-                cost = l.max(axis=2) * BIGF + l.sum(axis=2)
-                cost[~kv[j:j + block]] = np.inf
-                c = np.argmin(cost, axis=1)
-                chosen_local[j:j + block] = c
-                np.add.at(loads, bc[ar(len(c)), c].ravel(), 1)
-                loads[SEN] = 0
-            done += B
-            # write winners straight into the CSR skeleton
-            gid = gid0[si] + idx
-            sel = chan_c[ar(B), chosen_local]
-            selvc = vc_c[ar(B), chosen_local]
-            pos = ar(Lc)[None, :]
-            live = pos < fl[:, None]
-            flat = (hop_indptr[gid][:, None] + pos)[live]
-            chan_flat[flat] = sel[live]
-            vc_flat[flat] = selvc[live]
-            chosen_k[gid] = chosen_local
-            t_greedy += time.time() - t1
-    stats["walk_s"] = round(t_walk, 3)
-    stats["greedy_s"] = round(t_greedy, 3)
+            with obs.span("routing.select.walk") as sp:
+                s0 = si * shard_sources
+                srcs = np.arange(s0, min(s0 + shard_sources, n))
+                fl = flen[idx]
+                # adaptive budget: full K for flows touching the hot set
+                lm_run = int(loads[:SEN].max())
+                if lm_run > 1:
+                    hotc = np.nonzero(
+                        loads[:SEN] >= max(2, int(hot_load_frac * lm_run)))[0]
+                    hot_nodes = np.zeros(n, bool)
+                    hot_nodes[ch.src[hotc]] = True
+                    hot_nodes[ch.dst[hotc]] = True
+                    hot_f = hot_nodes[s0 + fb[idx]] | hot_nodes[fd[idx]]
+                else:
+                    hot_f = np.zeros(len(idx), bool)
+                uq = shard_uniq[si][idx]
+                kcap = np.where(hot_f, K, k_min)
+                kcap = np.minimum(kcap, np.where(fl == 1, 1,
+                                                 np.where(fl == 2, 2, K)))
+                kcap = np.where(uq, 1, kcap)
+                k_full_flows += int((kcap >= K).sum())
+                chan_c, vc_c, kv = _walk_flows(sg, n, n_vc, SEN,
+                                               shard_dist[si], shard_best[si],
+                                               srcs, fb[idx], fd[idx], fl,
+                                               kcap, K, uniq=uq)
+            t_walk += sp.seconds
+            with obs.span("routing.select.greedy") as sp:
+                B, _, Lc = chan_c.shape
+                # fold this slice into the expected-load prior (uniform over
+                # each flow's valid slots), then damp the greedy with the
+                # scaled unprocessed remainder. Round 1 alone is an unbiased
+                # sample of every shard, so later rounds skip the scatter
+                # (it costs ~F*K*L adds) and reuse the round-1 estimate.
+                if r == 0 and damp > 0.0:
+                    w = kv / kv.sum(axis=1)[:, None]
+                    np.add.at(ehat, chan_c.ravel(),
+                              np.repeat(w.ravel(), Lc))
+                    ehat[SEN] = 0.0
+                    ehat_flows += B
+                scale = damp * (1.0 - done / F) * (F / max(ehat_flows, 1)) \
+                    if ehat_flows else 0.0
+                chosen_local = np.zeros(B, np.int64)
+                for j in range(0, B, block):
+                    bc = chan_c[j:j + block]
+                    l = loads[bc].astype(np.float64)
+                    if scale > 0.0:
+                        l += scale * ehat[bc]
+                    cost = l.max(axis=2) * BIGF + l.sum(axis=2)
+                    cost[~kv[j:j + block]] = np.inf
+                    c = np.argmin(cost, axis=1)
+                    chosen_local[j:j + block] = c
+                    np.add.at(loads, bc[ar(len(c)), c].ravel(), 1)
+                    loads[SEN] = 0
+                done += B
+                # write winners straight into the CSR skeleton
+                gid = gid0[si] + idx
+                sel = chan_c[ar(B), chosen_local]
+                selvc = vc_c[ar(B), chosen_local]
+                pos = ar(Lc)[None, :]
+                live = pos < fl[:, None]
+                flat = (hop_indptr[gid][:, None] + pos)[live]
+                chan_flat[flat] = sel[live]
+                vc_flat[flat] = selvc[live]
+                chosen_k[gid] = chosen_local
+            t_greedy += sp.seconds
+    stats["walk_s"] = t_walk
+    stats["greedy_s"] = t_greedy
     stats["k_full_flows"] = k_full_flows
     stats["greedy_l_max"] = int(loads[:SEN].max())
 
     # ---- cross-shard refinement over the hottest channels ----------------
-    t0 = time.time()
-    stats.update({"refine_pool": 0, "refine_moved": 0, "refine_iters": 0,
-                  "refine_thresh": 0})
-    if local_search_rounds > 0:
-        flow_of_hop = np.repeat(ar(F, dtype=np.int64), flen_all)
-        for _ in range(refine_iters):
-            lm_before = int(loads[:SEN].max())
-            pool, thresh = _hot_pool(loads, chan_flat, flow_of_hop,
-                                     refine_cap, SEN)
-            if not len(pool):
-                break
-            stats["refine_iters"] += 1
-            stats["refine_pool"] = max(stats["refine_pool"], len(pool))
-            stats["refine_thresh"] = thresh
-            # re-walk the pool at full K (cached distances; budgeted
-            # slots reproduce, so chosen_k still indexes correctly)
-            seg = np.searchsorted(pool, gid0)
-            parts = []
-            Lp = 1
-            for si in range(n_shards):
-                a, b = seg[si], seg[si + 1]
-                if a == b:
-                    continue
-                loc = pool[a:b] - gid0[si]
-                s0 = si * shard_sources
-                srcs = np.arange(s0, min(s0 + shard_sources, n))
-                fl = shard_flen[si][loc]
-                uq = shard_uniq[si][loc]
-                cc, vv, kvp = _walk_flows(
-                    sg, n, n_vc, SEN, shard_dist[si], shard_best[si],
-                    srcs, shard_fb[si][loc], shard_fd[si][loc], fl,
-                    np.where(uq, 1, K).astype(np.int64), K, uniq=uq)
-                parts.append((cc, vv, kvp))
-                Lp = max(Lp, cc.shape[2])
+    with obs.span("routing.select.refine") as s_refine:
+        stats.update({"refine_pool": 0, "refine_moved": 0, "refine_iters": 0,
+                      "refine_thresh": 0})
+        if local_search_rounds > 0:
+            flow_of_hop = np.repeat(ar(F, dtype=np.int64), flen_all)
+            for _ in range(refine_iters):
+                lm_before = int(loads[:SEN].max())
+                pool, thresh = _hot_pool(loads, chan_flat, flow_of_hop,
+                                         refine_cap, SEN)
+                if not len(pool):
+                    break
+                stats["refine_iters"] += 1
+                stats["refine_pool"] = max(stats["refine_pool"], len(pool))
+                stats["refine_thresh"] = thresh
+                # re-walk the pool at full K (cached distances; budgeted
+                # slots reproduce, so chosen_k still indexes correctly)
+                seg = np.searchsorted(pool, gid0)
+                parts = []
+                Lp = 1
+                for si in range(n_shards):
+                    a, b = seg[si], seg[si + 1]
+                    if a == b:
+                        continue
+                    loc = pool[a:b] - gid0[si]
+                    s0 = si * shard_sources
+                    srcs = np.arange(s0, min(s0 + shard_sources, n))
+                    fl = shard_flen[si][loc]
+                    uq = shard_uniq[si][loc]
+                    cc, vv, kvp = _walk_flows(
+                        sg, n, n_vc, SEN, shard_dist[si], shard_best[si],
+                        srcs, shard_fb[si][loc], shard_fd[si][loc], fl,
+                        np.where(uq, 1, K).astype(np.int64), K, uniq=uq)
+                    parts.append((cc, vv, kvp))
+                    Lp = max(Lp, cc.shape[2])
 
-            def padc(a, fill):
-                if a.shape[2] == Lp:
-                    return a
-                out = np.full(a.shape[:2] + (Lp,), fill, a.dtype)
-                out[:, :, :a.shape[2]] = a
-                return out
+                def padc(a, fill):
+                    if a.shape[2] == Lp:
+                        return a
+                    out = np.full(a.shape[:2] + (Lp,), fill, a.dtype)
+                    out[:, :, :a.shape[2]] = a
+                    return out
 
-            candP = np.concatenate([padc(p[0], SEN) for p in parts])
-            vcP = np.concatenate([padc(p[1], 0) for p in parts])
-            kvP = np.concatenate([p[2] for p in parts])
-            P = len(pool)
-            pchosen = chosen_k[pool].astype(np.int64)
-            old_pchosen = pchosen.copy()
-            loads, pchosen = _refine_candidates(
-                loads, candP, kvP, pchosen, rng, SEN, np.int64(BIGF),
-                local_search_rounds, refine_block, lm_before)
-            # write the moved flows back into the CSR arrays
-            moved = np.nonzero(pchosen != old_pchosen)[0]
-            stats["refine_moved"] += len(moved)
-            if len(moved):
-                mg = pool[moved]
-                lens = flen_all[mg]
-                sel = candP[moved, pchosen[moved]]
-                selvc = vcP[moved, pchosen[moved]]
-                pos = ar(Lp)[None, :]
-                live = pos < lens[:, None]
-                flat = (hop_indptr[mg][:, None] + pos)[live]
-                chan_flat[flat] = sel[live]
-                vc_flat[flat] = selvc[live]
-                chosen_k[mg] = pchosen[moved]
-            if int(loads[:SEN].max()) >= lm_before:
-                break
-    stats["refine_s"] = round(time.time() - t0, 3)
+                candP = np.concatenate([padc(p[0], SEN) for p in parts])
+                vcP = np.concatenate([padc(p[1], 0) for p in parts])
+                kvP = np.concatenate([p[2] for p in parts])
+                P = len(pool)
+                pchosen = chosen_k[pool].astype(np.int64)
+                old_pchosen = pchosen.copy()
+                loads, pchosen = _refine_candidates(
+                    loads, candP, kvP, pchosen, rng, SEN, np.int64(BIGF),
+                    local_search_rounds, refine_block, lm_before)
+                # write the moved flows back into the CSR arrays
+                moved = np.nonzero(pchosen != old_pchosen)[0]
+                stats["refine_moved"] += len(moved)
+                if len(moved):
+                    mg = pool[moved]
+                    lens = flen_all[mg]
+                    sel = candP[moved, pchosen[moved]]
+                    selvc = vcP[moved, pchosen[moved]]
+                    pos = ar(Lp)[None, :]
+                    live = pos < lens[:, None]
+                    flat = (hop_indptr[mg][:, None] + pos)[live]
+                    chan_flat[flat] = sel[live]
+                    vc_flat[flat] = selvc[live]
+                    chosen_k[mg] = pchosen[moved]
+                if int(loads[:SEN].max()) >= lm_before:
+                    break
+    stats["refine_s"] = s_refine.seconds
 
     loads_final = loads[:SEN].astype(np.float64)
     return RoutingResult(csr, loads_final, float(loads_final.max()),

@@ -41,6 +41,8 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core import obs
+
 
 @dataclasses.dataclass(frozen=True)
 class CompiledTraffic:
@@ -232,10 +234,17 @@ def compile_flow_traffic(traffic, src_indptr: np.ndarray,
     stacked along a leading axis), or ``None`` for uniform.
     ``src_indptr``/``dst`` come straight from the ``CSRPathTable``. Rows
     are processed in blocks of ``block`` sources so the padded
-    (block, max_deg) staging arrays stay small at 4096 chips.
+    (block, max_deg) staging arrays stay small at 4096 chips. The call
+    is the program span ``traffic.compile``.
     """
+    with obs.span("traffic.compile"):
+        return _compile_flow_traffic(traffic, src_indptr, dst, block)
+
+
+def _compile_flow_traffic(traffic, src_indptr: np.ndarray, dst: np.ndarray,
+                          block: int) -> CompiledFlowTraffic:
     if isinstance(traffic, PhasedTraffic):
-        parts = [compile_flow_traffic(p, src_indptr, dst, block=block)
+        parts = [_compile_flow_traffic(p, src_indptr, dst, block)
                  for p in traffic.patterns]
         phase_of = np.repeat(
             np.arange(len(parts), dtype=np.int32),
