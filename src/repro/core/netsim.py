@@ -189,6 +189,17 @@ def _sweep_csr(ch_dst, pvf, hptr, lenm1, dstN, src_ptr, deg, fprob, falias,
     flat queue id factors as ``fc * n_vc + v`` with ``fc = l*C + c``, the
     single-lane arbitration/rank formulas carry over verbatim.)
 
+    On a TPU a gather or scatter costs about the same per index whatever
+    the size of its table, so the cycle body gathers and scatters only at
+    indices the data decides. What the queue layout or the loop fixes is
+    dense: each ring's head slot is a select over the slots axis; a
+    channel's winning VC is a one-hot over its ``n_vc`` queues, which also
+    reads the winner's word, target and consume flag and applies the pops
+    to ``head`` and ``size``; the adaptive candidates and their liveness
+    are computed before the loop, and their occupancy is read once per
+    (lane, node, candidate). ``tests/test_netsim_gathers.py`` keeps the
+    count of gathered and scattered indices per cycle.
+
     Route lookups are flow-native: a head word's next (channel, VC) is
     ``pvf[hptr[flow] + hop + 1]`` and it consumes when ``hop`` reaches
     ``lenm1[flow]`` -- no (n, n, MAXHOP) arrays anywhere. ``pvf`` packs
@@ -240,6 +251,10 @@ def _sweep_csr(ch_dst, pvf, hptr, lenm1, dstN, src_ptr, deg, fprob, falias,
 
     srcs = jnp.tile(jnp.arange(n), R)            # local node ids per lane
     lane_q = (jnp.arange(N) // n) * (n_ch * n_vc)
+    dg = deg[srcs]                               # flows per source
+    ptr0 = src_ptr[srcs]                         # first flow slot per source
+    vcs = jnp.arange(n_vc)
+    slot_ids = jnp.arange(slots)
     if T:
         word_tenant = lambda w: tof[w & _FLOW_MASK]   # noqa: E731
     if not phased:
@@ -250,6 +265,17 @@ def _sweep_csr(ch_dst, pvf, hptr, lenm1, dstN, src_ptr, deg, fprob, falias,
         node_q = jnp.tile(ch_dst, R)[jnp.arange(NQ) // n_vc]
         vc_q = jnp.arange(NQ) % n_vc
         qrows = jnp.arange(NQ)
+        out_ch = jnp.clip(outch, 0, n_ch - 1)                  # (n, D)
+        cand_ch = out_ch[node_q]                                # (NQ, D)
+        # the candidates' queues are shared by every queue at a node: a
+        # cycle reads them once per (lane, node, candidate) as rows of
+        # n_vc, then each queue takes the rows of its own (lane, node)
+        cand_row = (jnp.arange(R)[:, None, None] * n_ch
+                    + out_ch[None]).reshape(-1)
+        node_row = (qrows // (n_ch * n_vc)) * n + node_q        # (NQ,)
+        if faulted:
+            # candidate liveness on both planes; a cycle selects its plane
+            cand_alive = alive[:, cand_ch] > 0                 # (2, NQ, D)
 
     def cycle(carry):
         i, q, head, size, rr, busy, key, stall, wstall, stalled_at, \
@@ -261,7 +287,9 @@ def _sweep_csr(ch_dst, pvf, hptr, lenm1, dstN, src_ptr, deg, fprob, falias,
 
         with jax.named_scope("route"):
             # ---- head packet per (lane, channel, vc) ------------------------
-            hw = q[jnp.arange(NQ), head]
+            # a dense select of each ring's head slot, not a gather
+            hw = jnp.sum(jnp.where(slot_ids[None, :] == head[:, None], q, 0),
+                         axis=1)
             hf = hw & _FLOW_MASK
             hh = (hw >> _HOP_SHIFT) & _HOP_MASK
             nonempty = size > 0
@@ -273,22 +301,31 @@ def _sweep_csr(ch_dst, pvf, hptr, lenm1, dstN, src_ptr, deg, fprob, falias,
                 # escape lane (VC0 over the tree) as the safe fallback
                 dq = dstN[hf]
                 consume_q = nonempty & (node_q == dq)
-                cand_ch = jnp.clip(outch[node_q], 0, n_ch - 1)     # (NQ, D)
                 mm = minmask[ph, node_q, dq]
                 ok_cand = ((mm[:, None] >> jnp.arange(D)[None, :]) & 1) > 0
                 if faulted:
-                    ok_cand = ok_cand & (alive[ph, cand_ch] > 0)
+                    ok_cand = ok_cand & jnp.where(ph > 0, cand_alive[1],
+                                                  cand_alive[0])
                 # free space of the queue the packet would actually join:
                 # its destination-bound adaptive VC on each candidate channel
-                vq = (1 + dq % (n_vc - 1))[:, None]
-                occ = size[lane_base[:, None] + cand_ch * n_vc + vq]
+                vq = 1 + dq % (n_vc - 1)
+                rows = size.reshape(C, n_vc)[cand_row].reshape(R * n, D,
+                                                                n_vc)
+                occ = jnp.sum(jnp.where(vcs == vq[:, None, None],
+                                        rows[node_row], 0), axis=2)
                 score = jnp.where(ok_cand, slots - occ, -1)
                 # rotate tie-breaks per (queue, cycle): equal scores would
                 # otherwise herd every packet at a node onto one alternate
                 rot = (jnp.arange(D)[None, :] + qrows[:, None] + i) % D
-                j = jnp.argmax(score * D + rot, axis=1)
-                best_ch = cand_ch[qrows, j]
-                has_cand = score[qrows, j] >= 0
+                # rot is a permutation of 0..D-1 per row, so the keys of a
+                # row differ and the largest picks one candidate; as
+                # 0 <= rot < D, its floor over D is that candidate's score
+                key_d = score * D + rot
+                top = jnp.max(key_d, axis=1)
+                best_ch = jnp.sum(jnp.where(key_d == top[:, None], cand_ch, 0),
+                                  axis=1)
+                best_score = top // D
+                has_cand = best_score >= 0
                 # destination-bound adaptive VC: confines any one endpoint's
                 # backlog to a single VC per channel, so victim flows keep
                 # the other adaptive VCs (least-occupied selection was
@@ -307,7 +344,7 @@ def _sweep_csr(ch_dst, pvf, hptr, lenm1, dstN, src_ptr, deg, fprob, falias,
                        == my_ch)
                 chan_s = pvf[jnp.minimum(hptr[hf] + hh + 1, H - 1)] // n_vc
                 prim_occ = size[lane_base + chan_s * n_vc + bv]
-                best_occ = slots - score[qrows, j]    # slots + 1 when no cand
+                best_occ = slots - best_score         # slots + 1 when no cand
                 prim_take = on_path & ~consume_q & (prim_occ < slots) \
                     & (prim_occ <= best_occ + 4)
                 if faulted:
@@ -347,20 +384,25 @@ def _sweep_csr(ch_dst, pvf, hptr, lenm1, dstN, src_ptr, deg, fprob, falias,
             # ---- round-robin arbitration: one vc per channel ----------------
             # multi-flit packets occupy the link for `flits` cycles
             eligible = eligible & jnp.repeat(busy == 0, n_vc)
-            elig_cv = eligible.reshape(C, n_vc)
-            offs = (rr[:, None] + jnp.arange(n_vc)[None, :]) % n_vc
-            pri = jnp.take_along_axis(elig_cv, offs, axis=1)
-            first = jnp.argmax(pri, axis=1)
-            any_e = pri.any(axis=1)
-            win_v = (rr + first) % n_vc
-            win_q = jnp.arange(C) * n_vc + win_v             # (C,)
-            win_valid = any_e
+            # the winner is the eligible vc nearest past the pointer rr
+            # (rr itself first); with none eligible win_v stays rr
+            gap = jnp.where(eligible.reshape(C, n_vc),
+                            (vcs[None, :] - rr[:, None]) % n_vc, n_vc)
+            nearest = jnp.min(gap, axis=1)
+            win_valid = nearest < n_vc
+            win_v = (rr + nearest) % n_vc
+            win_oh = vcs[None, :] == win_v[:, None]          # (C, n_vc)
             rr = jnp.where(win_valid, (win_v + 1) % n_vc, rr)
 
-            w_word = hw[win_q]
+            def at_win(x):      # x per queue -> x of each channel's winner
+                return jnp.sum(jnp.where(win_oh, x.reshape(C, n_vc), 0),
+                               axis=1)
+
+            w_word = at_win(hw)
             w_tag = (w_word >> _TAG_SHIFT) & 1
-            w_consume = consume_q[win_q] & win_valid
-            w_target = jnp.where(win_valid & ~w_consume, tq[win_q], -1)
+            w_consume = jnp.any(win_oh & consume_q.reshape(C, n_vc), axis=1) \
+                & win_valid
+            w_target = jnp.where(win_valid & ~w_consume, at_win(tq), -1)
 
         with jax.named_scope("crossbar"):
             # ---- crossbar constraint: one push per target queue per cycle ---
@@ -374,6 +416,7 @@ def _sweep_csr(ch_dst, pvf, hptr, lenm1, dstN, src_ptr, deg, fprob, falias,
                 .min(jnp.arange(C, dtype=jnp.int32))
             w_push = cand & (first[tgt] == jnp.arange(C))
             w_pop = w_consume | w_push
+            pop_q = (win_oh & w_pop[:, None]).reshape(NQ)   # popped queues
             busy = jnp.where(w_pop, flits - 1, jnp.maximum(busy - 1, 0))
 
         with jax.named_scope("push"):
@@ -406,10 +449,9 @@ def _sweep_csr(ch_dst, pvf, hptr, lenm1, dstN, src_ptr, deg, fprob, falias,
             else:
                 want = jax.random.uniform(k1, (N,)) < thr
             u1 = jax.random.uniform(k2, (N,))
-            dg = deg[srcs]
             j = jnp.minimum((u1 * dg.astype(jnp.float32)).astype(jnp.int32),
                             dg - 1)
-            f0 = src_ptr[srcs] + jnp.maximum(j, 0)
+            f0 = ptr0 + jnp.maximum(j, 0)
             u2 = jax.random.uniform(k3, (N,))
             fid = jnp.where(u2 < fp[f0], f0, fa[f0])
             cv0 = pvf[hptr[fid]]
@@ -432,16 +474,15 @@ def _sweep_csr(ch_dst, pvf, hptr, lenm1, dstN, src_ptr, deg, fprob, falias,
                 iq = lane_q + cv0
             else:
                 iq = lane_q + cv0
-            # queue iq was popped this cycle iff its channel's winner is iq
-            i_pop = (w_pop[iq // n_vc]
-                     & (win_q[iq // n_vc] == iq)).astype(jnp.int32)
+            i_pop = pop_q[iq].astype(jnp.int32)
             # at most one push lands in iq this cycle (crossbar constraint)
             i_push = (first[iq] < C).astype(jnp.int32)
-            has_space = size[iq] - i_pop + i_push < slots
+            i_size = size[iq]
+            has_space = i_size - i_pop + i_push < slots
             inj = want & has_space & (dg > 0)
             if adaptive or faulted:
                 inj = inj & ok0
-            i_slot = (head[iq] + size[iq] + i_push) % slots
+            i_slot = (head[iq] + i_size + i_push) % slots
             inj_word = _pack_flow(fid, jnp.zeros((N,), jnp.int32),
                                   measure & inj)
 
@@ -453,13 +494,10 @@ def _sweep_csr(ch_dst, pvf, hptr, lenm1, dstN, src_ptr, deg, fprob, falias,
             all_words = jnp.concatenate([push_word, inj_word])
             q = q.at[all_rows, all_slots].set(all_words, mode="drop")
 
-            # ---- one fused scatter-add for every size delta, one for heads --
-            popq = jnp.where(w_pop, win_q, NQ)
-            d_rows = jnp.concatenate([popq, all_rows])
-            d_vals = jnp.concatenate([jnp.full((C,), -1, jnp.int32),
-                                      jnp.ones((C + N,), jnp.int32)])
-            size = size.at[d_rows].add(d_vals, mode="drop")
-            head = head.at[popq].add(1, mode="drop") % slots
+            # ---- pops are dense; one scatter-add for pushes + injections ---
+            pop_i = pop_q.astype(jnp.int32)
+            size = (size - pop_i).at[all_rows].add(1, mode="drop")
+            head = (head + pop_i) % slots
 
         with jax.named_scope("counters"):
             meas = jnp.where(measure, 1, 0)
@@ -477,7 +515,7 @@ def _sweep_csr(ch_dst, pvf, hptr, lenm1, dstN, src_ptr, deg, fprob, falias,
                 # per-(lane, tenant) accounting; flow -> tenant is static
                 # (`tof`), so attribution costs two gathers and two
                 # scatter-adds, no extra RNG
-                t_w = tof[hf[win_q]]
+                t_w = tof[w_word & _FLOW_MASK]
                 ok_w = w_consume & (t_w >= 0)
                 rowc = (jnp.arange(C) // n_ch) * T + jnp.clip(t_w, 0, T - 1)
                 cons_t = cons_t.at[rowc].add(ok_w.astype(jnp.int32))
@@ -490,11 +528,10 @@ def _sweep_csr(ch_dst, pvf, hptr, lenm1, dstN, src_ptr, deg, fprob, falias,
 
             if adaptive:
                 # per-queue persistent-stall counter (drives escape diversion)
-                popped = w_pop[qrows // n_vc] & (win_q[qrows // n_vc] == qrows)
-                stall = jnp.where(nonempty & ~popped, stall + 1, 0)
+                stall = jnp.where(nonempty & ~pop_q, stall + 1, 0)
                 # escape diversions: pushes that land on VC0 from a VC >= 1
                 escaped = escaped + (w_push & (tgt % n_vc == 0)
-                                     & (win_q % n_vc != 0)).reshape(
+                                     & (win_v != 0)).reshape(
                     R, n_ch).sum(axis=1)
 
         with jax.named_scope("watchdog"):

@@ -5,7 +5,10 @@ next channel or is consumed. Both kernels count it per rate lane
 (``hops``), bit-identically on the static, adaptive and fault paths; a
 packet consumed has been popped once per channel it crossed, so the
 count bounds ``consumed_total`` from above and equals it when every
-route is one hop long.
+route is one hop long. The kernels agree on every counter with each of
+the CSR kernel's flags, on the four-VC queue layout and on the two-VC
+one, so that the kernel's pick of one VC per channel sees more than two
+VCs.
 """
 import numpy as np
 import pytest
@@ -13,22 +16,52 @@ import pytest
 from repro.core import fault as F, netsim as NS, routing as R, \
     topology as T
 from repro.core.pathtable import CSRPathTable
+from repro.core.traffic import (PhasedTraffic, TenantSpec, TrafficPattern,
+                                compose_tenants)
 
 SHORT = dict(cycles=400, warmup=100)
 RATES = [0.05, 0.3]
 
 
-@pytest.fixture(scope="module")
-def pod():
+def _routed(n_vc):
     topo = T.pt((4, 4, 4))
-    at = R.allowed_turns(topo, n_vc=4, priority="robust")
+    at = R.allowed_turns(topo, n_vc=n_vc, priority="robust")
     sel = R.select_paths(at, K=4, local_search_rounds=1, engine="sharded")
     return topo, at, NS.at_tables(topo, at, sel, reserve_escape=True)
+
+
+@pytest.fixture(scope="module")
+def pod():
+    return _routed(4)
+
+
+@pytest.fixture(scope="module")
+def pod2():
+    return _routed(2)
+
+
+def _traffic(case, n):
+    if case == "bursty":
+        return TrafficPattern.uniform(n).with_burst(64, duty=0.25, gain=3.0)
+    if case == "tenants":
+        rng = np.random.default_rng(0)
+        half = n // 2
+        return compose_tenants(n, [
+            TenantSpec("a", np.arange(half), rng.random((half, half))),
+            TenantSpec("b", np.arange(half - 8, n),
+                       rng.random((n - half + 8, n - half + 8)), 0.5)])
+    return PhasedTraffic("two", (TrafficPattern.uniform(n),
+                                 TrafficPattern.hotspot(n, frac=0.4)),
+                         (64, 96))
 
 
 def _kw(case, topo, at):
     if case == "static":
         return {}
+    if case in ("bursty", "tenants", "phased"):
+        return {"traffic": _traffic(case, topo.n)}
+    if case == "adaptive":
+        return {"adaptive": NS.adaptive_spec(topo)}
     ev = F.fault_event(at, F.colors_in_use(topo)[0], 150)
     if case == "fault":
         return {"fault": ev}
@@ -36,10 +69,17 @@ def _kw(case, topo, at):
             "adaptive": NS.adaptive_spec(topo, dead_channels=ev[1])}
 
 
-@pytest.mark.parametrize("case", ["static", "fault", "adaptive-fault"])
-def test_hops_bit_identical_across_kernels(pod, case):
-    topo, at, tab = pod
-    kw = _kw(case, topo, at)
+FLAGS = ["static", "fault", "adaptive", "adaptive-fault", "bursty",
+         "tenants", "phased"]
+
+
+@pytest.mark.parametrize(
+    "case", ["static", "fault", "adaptive-fault", "adaptive", "bursty",
+             "tenants", "phased"] + [f"2vc-{f}" for f in FLAGS])
+def test_hops_bit_identical_across_kernels(pod, pod2, case):
+    topo, at, tab = pod2 if case.startswith("2vc-") else pod
+    assert tab.n_vc == (2 if case.startswith("2vc-") else 4)
+    kw = _kw(case.removeprefix("2vc-"), topo, at)
     tc = NS.sweep(tab, RATES, kernel="csr", seed=7, **SHORT, **kw)
     td = NS.sweep(tab, RATES, kernel="dense", seed=7, **SHORT, **kw)
     assert [r["hops"] for r in tc] == [r["hops"] for r in td]
