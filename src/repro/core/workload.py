@@ -21,7 +21,9 @@ that loop end to end:
    as a :class:`~repro.core.traffic.PhasedTraffic` (in-cube TP/EP
    all-to-all phase -> cross-cube DP-ring phase -> uniform background,
    durations proportional to wire bytes) for the simulator's
-   trace-replay mode;
+   trace-replay mode; :func:`ep_decode_trace` -- a wide expert-parallel
+   decode step routed token by token by a published router
+   (:data:`ROUTERS`), one dispatch and one combine phase per MoE layer;
 5. :func:`evaluate_workload` -- demand-weighted MCF + trace-replay
    saturation of any topology on a workload, routed through
    :func:`repro.core.pipeline.route_pod` (the headline
@@ -36,16 +38,17 @@ genuinely differ, which is the point.
 from __future__ import annotations
 
 import dataclasses
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.configs.registry import get_config, get_shape
-from repro.core import demand
+from repro.core import demand, obs
 from repro.core.demand import WorkloadDemand, weighted_mcf
 from repro.core.pipeline import PipelineConfig, route_pod
-from repro.core.topology import Pod
+from repro.core.topology import CUBE, Pod
 from repro.core.traffic import PhasedTraffic, TenantSpec, TrafficPattern
 
 _BF16 = 2.0     # bytes per element on the wire
@@ -171,6 +174,209 @@ def replay_trace(wd: WorkloadDemand, period: int = 256,
     cycles = [max(min_cycles, int(round(period * m / total)))
               for m in masses]
     return PhasedTraffic("trace", tuple(patterns), tuple(cycles))
+
+
+@dataclasses.dataclass(frozen=True)
+class RouterSpec:
+    """The published router settings of a routed-expert MoE: each token
+    scores every routed expert, keeps the ``topk_group`` best of
+    ``n_group`` equal expert groups (a group scores the sum of its two
+    best experts) and takes its ``experts_per_token`` best experts
+    inside them. Shared experts see every token where it is."""
+    n_routed_experts: int
+    experts_per_token: int
+    n_group: int
+    topk_group: int
+    n_shared_experts: int
+    scoring: str
+    hidden_size: int
+    moe_layers: int
+
+    @property
+    def dispatch_bytes(self) -> int:
+        """One token copy on its way to an expert: ``hidden_size`` FP8
+        values and one float32 scale per 128 of them."""
+        return self.hidden_size + 4 * (self.hidden_size // FP8_BLOCK)
+
+    @property
+    def combine_bytes(self) -> int:
+        """One expert output on its way back: ``hidden_size`` BF16."""
+        return 2 * self.hidden_size
+
+
+FP8_BLOCK = 128     # FP8 values per float32 scale on the wire
+TOPICS = 32         # clusters of decode hidden states, Zipf-weighted
+
+# https://huggingface.co/deepseek-ai/DeepSeek-V3/blob/main/config.json:
+# n_routed_experts, num_experts_per_tok, n_group, topk_group,
+# n_shared_experts, scoring_func, hidden_size; 61 layers of which
+# first_k_dense_replace = 3 are dense
+ROUTERS: Dict[str, RouterSpec] = {
+    "DeepSeek-V3": RouterSpec(n_routed_experts=256, experts_per_token=8,
+                              n_group=8, topk_group=4, n_shared_experts=1,
+                              scoring="sigmoid", hidden_size=7168,
+                              moe_layers=58),
+}
+
+
+def route_tokens(spec: RouterSpec, tokens: int, layers: int,
+                 seed: int) -> np.ndarray:
+    """(layers, tokens, experts_per_token) routed expert ids of a
+    decode step, drawn from ``seed``.
+
+    ``SeedSequence(seed)`` spawns ``layers + 1`` streams. Each token
+    belongs to one of :data:`TOPICS` topics, drawn from a Zipf law of
+    exponent 1 (``choice`` on the first stream). Layer ``l``'s logits
+    are ``A_l[topic] + eps``, with ``A_l`` (topics x experts) and ``eps``
+    (tokens x experts) standard normal float64 drawn in that order from
+    stream ``l + 1``: a random router acting on clustered hidden states,
+    drawn in distribution rather than through a ``hidden_size``-wide
+    matmul. Scores are ``sigmoid(logit)`` with the selection bias at 0.
+    Ties go to the lower group or expert index. Each token's experts come
+    out in ascending id order. Layers are routed in parallel threads
+    (NumPy releases the GIL in the draws and the array passes).
+    """
+    if spec.scoring != "sigmoid":
+        raise ValueError(f"unknown scoring {spec.scoring!r}")
+    streams = np.random.SeedSequence(seed).spawn(layers + 1)
+    w = 1.0 / np.arange(1, TOPICS + 1)
+    topic = np.random.default_rng(streams[0]).choice(TOPICS, size=tokens,
+                                                     p=w / w.sum())
+    with ThreadPoolExecutor() as pool:
+        return np.stack(list(pool.map(
+            lambda ss: _route_layer(spec, topic, np.random.default_rng(ss)),
+            streams[1:])))
+
+
+def _route_layer(spec: RouterSpec, topic: np.ndarray,
+                 rng: np.random.Generator) -> np.ndarray:
+    """(tokens, experts_per_token): one layer of :func:`route_tokens`."""
+    E, G, K = spec.n_routed_experts, spec.n_group, spec.experts_per_token
+    W = E // G
+    tokens = len(topic)
+    rows = np.arange(tokens)[:, None]
+    a = rng.standard_normal((TOPICS, E))
+    s = rng.standard_normal((tokens, E))
+    # s = sigmoid(a[topic] + eps), in place
+    s += a[topic]
+    np.negative(s, out=s)
+    np.exp(s, out=s)
+    s += 1.0
+    np.divide(1.0, s, out=s)
+    sg = s.reshape(tokens, G, W)
+    top2 = np.partition(sg, W - 2, axis=2)[:, :, W - 2:]
+    groups = np.sort(np.argsort(-(top2[:, :, 1] + top2[:, :, 0]),
+                                axis=1, kind="stable")
+                     [:, :spec.topk_group], axis=1)
+    # the kept groups' scores, in expert order
+    cand = sg[rows, groups].reshape(tokens, -1)
+    C = cand.shape[1]
+    kth = np.partition(cand, C - K, axis=1)[:, C - K:C - K + 1]
+    take = cand >= kth
+    tied = np.nonzero(take.sum(axis=1) != K)[0]
+    if len(tied):   # equal scores at the K-th place: lower ids first
+        c, v = cand[tied], kth[tied]
+        over, eq = c > v, c == v
+        take[tied] = over | (eq & (np.cumsum(eq, axis=1)
+                                   <= K - over.sum(axis=1, keepdims=True)))
+    j = np.nonzero(take)[1].reshape(tokens, K)
+    return np.take_along_axis(groups, j // W, axis=1) * W + j % W
+
+
+def ep_copies(experts: np.ndarray, tokens_per_chip: int, n: int,
+              experts_per_chip: int) -> np.ndarray:
+    """(layers, n, n) int64 token copies from chip to chip: one per
+    (token, distinct destination chip other than its own), expert ``e``
+    living on chip ``e // experts_per_chip``. Two experts on one chip
+    take one copy; an expert on the token's own chip takes none."""
+    L, T, _ = experts.shape
+    src = np.arange(T) // tokens_per_chip
+    out = np.zeros((L, n, n), np.int64)
+    for layer in range(L):
+        d = np.sort(experts[layer] // experts_per_chip, axis=1)
+        keep = d != src[:, None]
+        keep[:, 1:] &= d[:, 1:] != d[:, :-1]
+        pair = np.broadcast_to(src[:, None], d.shape)[keep] * n + d[keep]
+        out[layer] = np.bincount(pair, minlength=n * n).reshape(n, n)
+    return out
+
+
+def ep_decode_trace(pod, arch, *, tokens_per_chip: int, layers: int,
+                    period: int, seed: int,
+                    experts_per_chip: int) -> PhasedTraffic:
+    """One decode step of an expert-parallel MoE pool as a phased
+    schedule: dispatch of layer 1, combine of layer 1, ..., combine of
+    layer ``layers``.
+
+    ``pod`` is a :class:`~repro.core.topology.Pod` or its dims, chips
+    numbered ``x + X*y + X*Y*z``; ``arch`` a :data:`ROUTERS` name or a
+    :class:`RouterSpec`. Every chip holds ``tokens_per_chip`` tokens
+    (token ``t`` on chip ``t // tokens_per_chip``) and
+    ``experts_per_chip`` routed experts (expert ``e`` on chip
+    ``e // experts_per_chip``); attention and shared experts stay
+    local. Tokens are routed by :func:`route_tokens`. A dispatch phase's demand is the layer's
+    :func:`ep_copies` times :attr:`RouterSpec.dispatch_bytes`, its
+    combine phase the copies transposed times
+    :attr:`RouterSpec.combine_bytes`. A phase's source rates are
+    ``float32(row_bytes * n / phase_bytes)`` (mean 1) and its cycles
+    ``period * phase_bytes // step_bytes``, at least 1, the last phase
+    taking the rest of ``period``: each float the simulator reads
+    follows from integer byte counts.
+
+    The call is the program span ``workload.ep_trace``, with the stages
+    ``.route`` and ``.matrix``, and counts, over all layers,
+    ``workload.ep.copies`` (dispatch copies), ``.cross_cube_copies``,
+    ``.max_in_copies`` (the most copies one chip receives in one
+    dispatch phase) and ``.mean_in_copies`` (copies per chip and
+    dispatch phase, rounded down).
+    """
+    spec = ROUTERS[arch] if isinstance(arch, str) else arch
+    dims = tuple(pod.dims if isinstance(pod, Pod) else pod)
+    n = int(np.prod(dims))
+    if experts_per_chip * n != spec.n_routed_experts:
+        raise ValueError(f"{spec.n_routed_experts} experts do not fill "
+                         f"{n} chips at {experts_per_chip} per chip")
+    if not 1 <= layers <= spec.moe_layers:
+        raise ValueError(f"layers must be in [1, {spec.moe_layers}]")
+    with obs.span("workload.ep_trace"):
+        with obs.span("workload.ep_trace.route"):
+            experts = route_tokens(spec, n * tokens_per_chip, layers, seed)
+        with obs.span("workload.ep_trace.matrix"):
+            copies = ep_copies(experts, tokens_per_chip, n,
+                               experts_per_chip)
+            phases = []
+            for c in copies:
+                phases += [c * spec.dispatch_bytes,
+                           c.T * spec.combine_bytes]
+            totals = [int(b.sum()) for b in phases]
+            step = sum(totals)
+            if step == 0:
+                raise ValueError("the step sends nothing between chips")
+            cycles = [max(1, period * b // step) for b in totals[:-1]]
+            cycles.append(period - sum(cycles))
+            if cycles[-1] < 1:
+                raise ValueError("period too short for the phases")
+            patterns = tuple(
+                TrafficPattern.from_matrix(
+                    f"{'dispatch' if p % 2 == 0 else 'combine'}{p // 2}",
+                    b.astype(np.float64),
+                    src_rate=(b.sum(axis=1) * n / max(t, 1))
+                    .astype(np.float32))
+                for p, (b, t) in enumerate(zip(phases, totals)))
+            i = np.arange(n)
+            X, Y, _ = dims
+            cube = ((i % X) // CUBE, (i // X % Y) // CUBE,
+                    (i // (X * Y)) // CUBE)
+            cross = np.zeros((n, n), bool)
+            for axis in cube:
+                cross |= axis[:, None] != axis[None, :]
+            obs.count("workload.ep.copies", copies.sum())
+            obs.count("workload.ep.cross_cube_copies",
+                      copies[:, cross].sum())
+            obs.count("workload.ep.max_in_copies", copies.sum(axis=1).max())
+            obs.count("workload.ep.mean_in_copies",
+                      copies.sum() // (n * layers))
+    return PhasedTraffic(f"ep-decode-{seed}", patterns, tuple(cycles))
 
 
 def demand_pair_weight(wd: WorkloadDemand, cap: int = 64) -> np.ndarray:

@@ -85,6 +85,22 @@ def test_sweep_csr_adaptive_fault_8cube(one_chip):
     _assert_fits(compiled)
 
 
+def test_sweep_csr_phased_adaptive_4x4x8(one_chip):
+    """Trace replay with adaptive routing on a 128-chip slice: the eight
+    dispatch and combine phases of a DeepSeek-V3 expert-parallel decode
+    step, three rates."""
+    from repro.core import workload as W
+    topo = T.pt((4, 4, 8))
+    tab = NS.dor_tables(topo, n_vc=4)
+    trace = W.ep_decode_trace(topo.pod, "DeepSeek-V3", tokens_per_chip=64,
+                              layers=4, period=768, seed=0,
+                              experts_per_chip=2)
+    compiled = _compile_sweep(one_chip, tab, [0.2, 0.37, 0.49],
+                              traffic=trace, adaptive=NS.adaptive_spec(topo),
+                              fault=None)
+    _assert_fits(compiled)
+
+
 def test_pdhg_chunk_f64_synthesis_lp(one_chip):
     """One PDHG chunk in f64 on the 8^3 synthesis LP's operator."""
     A = SY.build_synthesis_lp(T.Pod((8, 8, 8))).A
